@@ -17,7 +17,8 @@ hot-swap the artifact with zero downtime:
         --map rooms-S --queries 250 --budget 0.4 --rounds 6
 
 ``--shards N`` serves through the region-sharded engine (DESIGN.md §9):
-the bucketed slabs are placed over N devices (forced host devices work:
+the bucketed slabs are placed one shard per device over N devices, and the
+run fails when the runtime has fewer (forced host devices work:
 ``XLA_FLAGS=--xla_force_host_platform_device_count=N``), batches route by
 (shard, bucket), and the answers are checked bitwise against the
 single-device engine — the CI sharded smoke gate:
@@ -42,17 +43,11 @@ from repro.core import (build_ehl, build_visgraph, bucketed_device_bytes,
                         slab_device_bytes, slab_layout, uniform_queries,
                         workload_scores)
 from repro.indexing import IndexManager
+from repro.launch.cache import enable_compile_cache
+from repro.launch.mesh import make_serving_mesh
 from repro.serving import PathServer, expected_join_cost, make_engine
 
-
-def serving_mesh_or_none(num_shards: int):
-    """A real N-device mesh when the runtime has one, else round-robin."""
-    from repro.launch.mesh import make_serving_mesh
-    try:
-        return make_serving_mesh(num_shards)
-    except ValueError as e:
-        print(f"note: {e}; round-robining shards onto available devices")
-        return None
+ROOT = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
 
 
 def main():
@@ -69,7 +64,9 @@ def main():
     ap.add_argument("--backend", choices=("jnp", "pallas", "host"),
                     default="jnp", help="query engine backend")
     ap.add_argument("--kernels", action="store_true",
-                    help="alias for --backend pallas (interpret on CPU)")
+                    help="alias for --backend pallas (kernels compile on a "
+                         "TPU and run in the Pallas interpreter on the CPU; "
+                         "other backends are refused)")
     ap.add_argument("--quantize", choices=("off", "bf16", "f16"),
                     default="off",
                     help="serve quantized label slabs (DESIGN.md §11): "
@@ -117,12 +114,12 @@ def main():
                          "Prometheus text parses and the expected series/"
                          "events are present (CI smoke gate)")
     ap.add_argument("--metrics-dir",
-                    default=os.path.join(os.path.dirname(
-                        os.path.abspath(__file__)), "..", "benchmarks",
-                        "artifacts", "telemetry"),
+                    default=os.path.join(ROOT, "benchmarks", "artifacts",
+                                         "telemetry"),
                     help="[metrics] output directory")
     args = ap.parse_args()
     backend = "pallas" if args.kernels else args.backend
+    print(f"compile cache: {enable_compile_cache(ROOT)}")
     if args.metrics:
         # compile/cost attribution (DESIGN.md §13) rides along with the
         # telemetry export; it must be enabled before the FIRST warmup —
@@ -428,12 +425,12 @@ def run_sharded(args, backend: str) -> None:
     if backend == "host":
         print("--shards needs a device backend (jnp|pallas)")
         sys.exit(2)
+    mesh = make_serving_mesh(args.shards)   # raises on too few devices
     scene = make_map(args.map, seed=0)
     graph = build_visgraph(scene)
     index = build_ehl(scene, cell_size=2.0, graph=graph)
     compress_to_fraction(index, args.budget)
 
-    mesh = serving_mesh_or_none(args.shards)
     lay = None if args.quantize == "off" else slab_layout(args.quantize)
     planner = ShardPlanner(args.shards, tol=args.shard_tol)
     plan = planner.plan(index)
@@ -528,7 +525,7 @@ def run_adaptive(args, backend: str) -> None:
         over_kw = dict(layout=lay) if lay is not None else {}
         budget += sharded_overhead_bytes(index, args.shards, **over_kw)
         shard_kw = dict(num_shards=args.shards,
-                        mesh=serving_mesh_or_none(args.shards),
+                        mesh=make_serving_mesh(args.shards),
                         shard_tol=args.shard_tol)
 
     # validate_tol=0: a candidate only goes live if the probe answers are

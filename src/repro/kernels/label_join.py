@@ -10,9 +10,11 @@ trade of redundant flops for regularity.  The kernel emits the *row join*
 so the output tile keeps the lane-aligned [B_BLK, L] shape; the final
 min-over-L happens in the jit wrapper (fused by XLA).
 
-Memory: per grid step the kernel holds 4 label tiles of [B_BLK, L] plus one
-[B_BLK, L, T_BLK] broadcast temp in VMEM; B_BLK=8, L<=2048, T_BLK=128 keeps
-the footprint under ~5 MB.
+Memory: the grid tiles the batch and the s-side label axis, so a grid step
+holds [B_BLK, S_BLK] s-side tiles, the whole [B_BLK, L] t-side rows, and one
+[B_BLK, S_BLK, T_BLK] broadcast temp in VMEM.  At B_BLK=8, S_BLK=512,
+T_BLK=128 the temp is 2 MB at every L; untiled on the s side it grows with
+L and the chip's compiler refuses L=2048 for lack of VMEM.
 """
 
 from __future__ import annotations
@@ -22,25 +24,25 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from .compat import tpu_compiler_params
+from jax.experimental.pallas import tpu as pltpu
 
 
 DEF_B_BLK = 8
+S_BLK = 512
 DEF_T_BLK = 128
 
 
 def _join_kernel(hub_s_ref, vd_s_ref, hub_t_ref, vd_t_ref, out_ref,
                  *, t_blk: int):
-    L = hub_s_ref.shape[1]
-    hub_s = hub_s_ref[...]             # [BB, L] int32
-    vd_s = vd_s_ref[...]               # [BB, L] f32
+    L = hub_t_ref.shape[1]
+    hub_s = hub_s_ref[...]             # [BB, S] int32
+    vd_s = vd_s_ref[...]               # [BB, S] f32
     inf = jnp.float32(jnp.inf)
 
     def body(k, matchmin):
         hub_t = hub_t_ref[:, pl.ds(k * t_blk, t_blk)]       # [BB, T]
         vd_t = vd_t_ref[:, pl.ds(k * t_blk, t_blk)]
-        eq = hub_s[:, :, None] == hub_t[:, None, :]         # [BB, L, T]
+        eq = hub_s[:, :, None] == hub_t[:, None, :]         # [BB, S, T]
         cand = jnp.min(jnp.where(eq, vd_t[:, None, :], inf), axis=-1)
         return jnp.minimum(matchmin, cand)
 
@@ -66,6 +68,9 @@ def label_join_rowmin(hub_s: jnp.ndarray, vd_s: jnp.ndarray,
     B, L = hub_s.shape
     b_pad = (-B) % b_blk
     l_pad = (-L) % t_blk
+    # s tiles: whole t tiles, at most S_BLK wide; L pads to whole s tiles
+    s_blk = min(max(t_blk, S_BLK // t_blk * t_blk), L + l_pad)
+    l_pad += (-(L + l_pad)) % s_blk
     inf = jnp.float32(jnp.inf)
 
     def padded(x, fill):
@@ -77,13 +82,16 @@ def label_join_rowmin(hub_s: jnp.ndarray, vd_s: jnp.ndarray,
     vt = padded(vd_t.astype(jnp.float32), inf)
     Bp, Lp = hs.shape
 
+    s_spec = pl.BlockSpec((b_blk, s_blk), lambda i, j: (i, j))
+    t_spec = pl.BlockSpec((b_blk, Lp), lambda i, j: (i, 0))
     out = pl.pallas_call(
         functools.partial(_join_kernel, t_blk=t_blk),
-        grid=(Bp // b_blk,),
-        in_specs=[pl.BlockSpec((b_blk, Lp), lambda i: (i, 0))] * 4,
-        out_specs=pl.BlockSpec((b_blk, Lp), lambda i: (i, 0)),
+        grid=(Bp // b_blk, Lp // s_blk),
+        in_specs=[s_spec, s_spec, t_spec, t_spec],
+        out_specs=s_spec,
         out_shape=jax.ShapeDtypeStruct((Bp, Lp), jnp.float32),
-        compiler_params=tpu_compiler_params(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(hs, vs, ht, vt)
     return out[:B, :L]

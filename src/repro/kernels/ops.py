@@ -1,7 +1,8 @@
 """Jit'd public wrappers: kernel / reference dispatch.
 
-``*_kernel`` entry points run the Pallas kernels (interpret=True off-TPU, so
-CPU CI exercises the exact kernel bodies); ``*_ref`` entry points are the
+``*_kernel`` entry points run the Pallas kernels: compiled by Mosaic on the
+TPU, in the Pallas interpreter on the CPU (the test backend, where the exact
+kernel bodies run), and nowhere else.  ``*_ref`` entry points are the
 pure-jnp oracles.  ``repro.core.packed.query_batch`` picks via its
 ``use_kernels`` flag; tests assert both paths agree.
 """
@@ -18,10 +19,15 @@ from .segvis import segvis_tiles as _segvis_tiles_pallas
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Compiled on the TPU, interpreted on the CPU; no other backend."""
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(f"the Pallas TPU kernels have no {backend!r} "
+                           "lowering; serve with the jnp backend there")
+    return backend == "cpu"
 
 
-# -- references (also the non-TPU production path) ---------------------------
+# -- references (the jnp engine's ops) ---------------------------------------
 segvis_ref = _ref.segvis_ref
 segvis_tiles_ref = _ref.segvis_tiles_ref
 label_join_ref = _ref.label_join_ref

@@ -2,7 +2,7 @@
 
 Every kernel in this package has its reference here; tests sweep shapes and
 assert allclose(kernel(interpret=True), ref).  These references are also the
-production fallback on non-TPU backends.
+ops of the jnp engine, the serving default on every backend.
 """
 
 from __future__ import annotations

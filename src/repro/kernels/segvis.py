@@ -30,9 +30,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from . import ref as _ref
-from .compat import tpu_compiler_params
 
 
 DEF_SEG_BLK = 256
@@ -114,7 +114,7 @@ def segvis(p: jnp.ndarray, q: jnp.ndarray, ea: jnp.ndarray, eb: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((1, seg_blk), lambda i, j: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, Np), jnp.int32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(pT, qT, eaT, ebT, ecT)
@@ -185,7 +185,7 @@ def segvis_tiles(p: jnp.ndarray, q: jnp.ndarray,
         in_specs=[seg_spec, seg_spec] + [tile_spec] * 6,
         out_specs=pl.BlockSpec((1, seg_blk), lambda i, j: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, Np), jnp.int32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(pT, qT, *tiles)

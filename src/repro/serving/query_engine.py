@@ -7,9 +7,9 @@ keeps stats and scatters results; *how* a batch is answered is an engine
 * :class:`HostEngine`   — the scalar float64 oracle (``repro.core.query``);
   slow, exact, the reference everything else is validated against.
 * :class:`JnpEngine`    — batched XLA engine over a packed layout, pure-jnp
-  ops (the production path on CPU/GPU).
+  ops (the serving default).
 * :class:`PallasEngine` — same engine routed through the Pallas TPU kernels
-  (interpret mode off-TPU, so the kernel bodies run everywhere).
+  (compiled on the TPU; interpreted on the CPU, where the tests run them).
 
 The device engines accept either packed layout: the single-slab
 ``PackedIndex`` (one bucket) or the width-bucketed ``BucketedIndex``

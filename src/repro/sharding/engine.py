@@ -90,8 +90,10 @@ class ShardedQueryEngine(QueryEngine):
     ``index``: a planned :class:`ShardedIndex`, or a host ``EHLIndex`` that
     is planned + packed here (``num_shards`` required).  ``mesh``: a
     ``launch.mesh.make_serving_mesh`` mesh; ``None`` round-robins shards
-    onto the available devices (single-device test mode — identical code
-    paths, the transfers just degenerate to same-device copies).
+    onto the available devices, stacking several per device only on the
+    CPU backend (single-device test mode — identical code paths, the
+    transfers just degenerate to same-device copies;
+    ``launch.mesh.shard_devices``).
     """
 
     name = "sharded"

@@ -33,10 +33,11 @@ def test_moe_ep_matches_gspmd_reference():
         from repro.configs import get_config
         from repro.models import layers as ll
         from repro.distributed import hints
-        from repro.distributed.compat import make_mesh
+        from jax.sharding import AxisType
         from repro.distributed.moe_ep import moe_block_ep
 
-        mesh = make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         cfg = get_config("deepseek-v3-671b").reduced()
         key = jax.random.PRNGKey(0)
         p = jax.tree.map(lambda a: a[0], ll.init_moe(cfg, key, 1, jnp.float32))
@@ -62,12 +63,13 @@ def test_flash_decode_matches_plain():
     out = _run("""
         import jax, jax.numpy as jnp
         from repro.distributed import hints
-        from repro.distributed.compat import make_mesh
+        from jax.sharding import AxisType
         from repro.distributed.flash_decode import (
             decode_attention_dist, seq_sharded_decode_applicable)
         from repro.models.layers import decode_attention
 
-        mesh = make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         B, Smax, K, H, hd = 4, 32, 3, 6, 16
         ks = jax.random.split(jax.random.PRNGKey(0), 5)
         q = jax.random.normal(ks[0], (B, 1, H, hd))
@@ -101,9 +103,10 @@ def test_train_step_on_8_device_mesh():
         from repro.optim import adamw
         from repro.distributed.sharding import param_shardings, batch_spec
         from repro.distributed import hints
-        from repro.distributed.compat import make_mesh
+        from jax.sharding import AxisType
 
-        mesh = make_mesh((4, 2), ("data", "model"))
+        mesh = jax.make_mesh((4, 2), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         cfg = get_config("tinyllama-1.1b").reduced()
         with hints.mesh_hints(mesh), mesh:
             pshapes = jax.eval_shape(

@@ -7,13 +7,15 @@ BlockSpec/padding plumbing.
 
 import numpy as np
 import pytest
+import jax
 import jax.numpy as jnp
+from jax.experimental.pallas import tpu as pltpu
 pytest.importorskip("hypothesis")  # test dep (pyproject [test]); skip, not error
 from hypothesis import given, settings, strategies as st
 
 from repro.kernels import ops
 from repro.kernels.label_join import label_join_rowmin
-from repro.kernels.segvis import segvis
+from repro.kernels.segvis import segvis, segvis_tiles
 
 
 def _rand_segs(rng, n, e):
@@ -87,6 +89,18 @@ def test_label_join_block_invariance(b_blk, t_blk):
     np.testing.assert_allclose(np.asarray(ref), np.asarray(ker), rtol=1e-6)
 
 
+@pytest.mark.parametrize("l,t_blk", [(200, 128), (640, 128), (900, 384),
+                                     (2048, 128)])
+def test_label_join_s_tiling_bitwise(l, t_blk):
+    """Tiling the s-side label axis (one to four S_BLK tiles, padded)
+    leaves every row join bit-identical to the reference."""
+    rng = np.random.default_rng(l)
+    hs, vs, ht, vt = _rand_join(rng, 9, l)
+    ref = ops.label_join_rowmin_ref(hs, vs, ht, vt)
+    ker = label_join_rowmin(hs, vs, ht, vt, t_blk=t_blk, interpret=True)
+    np.testing.assert_array_equal(np.asarray(ref), np.asarray(ker))
+
+
 @settings(max_examples=25, deadline=None)
 @given(st.integers(0, 2 ** 31 - 1))
 def test_label_join_property_matches_bruteforce(seed):
@@ -119,3 +133,36 @@ def test_all_inf_labels_give_inf():
     vs = jnp.full((b, l), jnp.inf, jnp.float32)
     out = ops.label_join_kernel(hs, vs, hs, vs, interpret=True)
     assert np.isinf(np.asarray(out)).all()
+
+
+def _pallas_calls(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for p in eqn.params.values():
+            inner = getattr(p, "jaxpr", None)
+            if inner is not None:
+                yield from _pallas_calls(getattr(inner, "jaxpr", inner))
+
+
+_I32 = jnp.zeros((8, 256), jnp.int32)
+_F32 = jnp.zeros((8, 256), jnp.float32)
+_PTS = jnp.zeros((256, 2), jnp.float32)
+_TILE = jnp.zeros((256, 128), jnp.float32)
+
+
+@pytest.mark.parametrize("fn,args,semantics", [
+    (label_join_rowmin, (_I32, _F32, _I32, _F32), ("parallel", "parallel")),
+    (segvis, (_PTS, _PTS, _PTS, _PTS, _PTS), ("parallel", "arbitrary")),
+    (segvis_tiles, (_PTS, _PTS) + (_TILE,) * 6, ("parallel", "arbitrary")),
+], ids=["label_join", "segvis", "segvis_tiles"])
+def test_kernels_pass_tpu_compiler_params(fn, args, semantics):
+    """Each kernel hands Mosaic a real ``pltpu.CompilerParams`` with its
+    grid's dimension semantics — a dropped hint would pass silently."""
+    jaxpr = jax.make_jaxpr(lambda *a: fn(*a, interpret=False))(*args)
+    calls = list(_pallas_calls(jaxpr.jaxpr))
+    assert calls
+    for eqn in calls:
+        params = eqn.params["compiler_params"]["mosaic_tpu"]
+        assert isinstance(params, pltpu.CompilerParams)
+        assert params.dimension_semantics == semantics

@@ -15,10 +15,11 @@ def test_pipeline_forward_and_grad_match():
         from repro.models import transformer as T
         from repro.models import layers as ll
         from repro.distributed import hints
-        from repro.distributed.compat import make_mesh
+        from jax.sharding import AxisType
         from repro.distributed.pipeline import pipeline_forward
 
-        mesh = make_mesh((2, 4), ("pod", "data"))
+        mesh = jax.make_mesh((2, 4), ("pod", "data"),
+                             axis_types=(AxisType.Auto,) * 2)
         cfg = get_config("tinyllama-1.1b").reduced()   # 4 layers, 2 stages
         params = T.init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
         toks = jax.random.randint(jax.random.PRNGKey(1), (8, 32), 0,

@@ -12,7 +12,9 @@ import os
 import subprocess
 import sys
 import textwrap
+from types import SimpleNamespace
 
+import jax
 import numpy as np
 import pytest
 
@@ -38,6 +40,28 @@ def sharded_setup(scene_s, graph_s, hl_s):
     planner = ShardPlanner(N_SHARDS)
     sharded = planner.build(idx)
     return idx, bx, sharded
+
+
+# --------------------------------------------------------------- placement
+
+def test_serving_mesh_refuses_fewer_devices_than_shards():
+    from repro.launch.mesh import make_serving_mesh
+    n = len(jax.devices()) + 1
+    with pytest.raises(ValueError, match=f"need {n} devices"):
+        make_serving_mesh(n)
+
+
+def test_meshless_placement_stacks_shards_only_on_cpu(monkeypatch):
+    from repro.launch.mesh import shard_devices
+    cpu = jax.devices()
+    assert shard_devices(None, len(cpu) + 1)[-1] == cpu[0]   # wraps on CPU
+    one_tpu = [SimpleNamespace(platform="tpu", id=0)]
+    monkeypatch.setattr(jax, "devices", lambda *a: one_tpu)
+    with pytest.raises(ValueError, match="refusing to stack"):
+        shard_devices(None, N_SHARDS)
+    four_tpus = [SimpleNamespace(platform="tpu", id=i) for i in range(4)]
+    monkeypatch.setattr(jax, "devices", lambda *a: four_tpus)
+    assert shard_devices(None, N_SHARDS) == four_tpus
 
 
 # ----------------------------------------------------------------- planner
