@@ -1,0 +1,8 @@
+"""The fold's analytic work (work.fold) over the device time of its
+programs, as a share of the chip's roofline (peaks.json)."""
+
+from metrics import _layer
+
+
+def read(m):
+    return _layer.roofline(m, "fold", "fold_endpoint")
