@@ -15,8 +15,12 @@ Three primitives, one bundle:
 registry defaults to the shared :data:`REGISTRY`).  ``Telemetry.off()``
 builds the disabled variant used by the instrumentation-overhead gate:
 sampling rate 0, events suppressed — the registry stays live because it
-*is* the serving stats.
+*is* the serving stats.  ``Telemetry.timeline`` (off by default, can be
+flipped on a live server) puts the batcher's ``serve.*`` stages on the JAX
+profiler's timeline as host events (:meth:`Telemetry.span`).
 """
+
+import contextlib
 
 from .events import EventLog
 from .locks import (LOCK_RANKS, LockOrderError, OrderedLock, held_locks,
@@ -48,8 +52,18 @@ __all__ = [
 ]
 
 
+_NO_SPAN = contextlib.nullcontext()
+
+
 class Telemetry:
-    """Sampler + trace ring + event log over a shared metrics registry."""
+    """Sampler + trace ring + event log over a shared metrics registry.
+
+    ``timeline``: when True, :meth:`span` records each serving stage as a
+    host event on the JAX profiler's clock, in the same trace as the
+    device's operations (an operator sets it before
+    ``jax.profiler.start_trace`` against a live server).  Off, a span
+    site is an attribute test and a shared no-op context.
+    """
 
     def __init__(self, registry: MetricsRegistry = None,
                  sample_rate: float = 0.05, slow_ms: float = 50.0,
@@ -60,6 +74,7 @@ class Telemetry:
         self.spans = TraceLog(capacity=span_capacity)
         self.events = EventLog(path=events_path) if events is None \
             else events
+        self.timeline = False
 
     @classmethod
     def off(cls, registry: MetricsRegistry = None) -> "Telemetry":
@@ -67,6 +82,14 @@ class Telemetry:
         t = cls(registry=registry, sample_rate=0.0, slow_ms=0.0)
         t.events.enabled = False
         return t
+
+    def span(self, name: str):
+        """Context manager: a profiler host event ``name`` while
+        ``timeline`` is on, else nothing."""
+        if not self.timeline:
+            return _NO_SPAN
+        from jax.profiler import TraceAnnotation  # lazy: obs stays jax-free
+        return TraceAnnotation(name)
 
     @property
     def enabled(self) -> bool:

@@ -16,15 +16,14 @@ stage           host routing + cross-shard gathers (``eng.stage``)
 dispatch        staged → device program issued (``dispatch_staged``)
 pipeline_wait   dispatched → retire loop turns to this flight
 device_join     ``block_until_ready`` wait — the device-time attribution
-rescue          quantized argmin residual rescue (engine-reported; 0 when
-                the layout is exact or rescue is fused into dispatch)
-unwind          path unwinding (async replies are distance/argmin only,
-                so 0 here; the sync ``query_paths`` span fills it)
 reply           scatter results to tickets + stats bookkeeping
 ==============  ========================================================
 
 Sync queries (``PathServer.query``/``query_paths``) reuse the same trace
-type with ``SYNC_STAGES`` (route → dispatch → rescue → unwind → reply).
+type with ``SYNC_STAGES`` (route → dispatch → rescue → unwind → reply):
+``query_paths`` fills ``unwind``; the async path replies with distances or
+argmins only and fuses any rescue into dispatch/device_join, so it has
+neither stage.
 
 The offline build pipeline reuses the same type with ``BUILD_STAGES``
 (plan → compress → repack → validate → stage → swap): one trace per
@@ -50,7 +49,7 @@ from .locks import make_lock
 
 ASYNC_STAGES: Tuple[str, ...] = (
     "admission", "queue_wait", "stage", "dispatch", "pipeline_wait",
-    "device_join", "rescue", "unwind", "reply")
+    "device_join", "reply")
 
 SYNC_STAGES: Tuple[str, ...] = (
     "route", "dispatch", "rescue", "unwind", "reply")

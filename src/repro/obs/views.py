@@ -53,6 +53,12 @@ class StatsView:
         for field, (name, _) in self._GAUGES.items():
             self._series[field] = self.registry.gauge(name, **lbl)
 
+    def inc(self, field: str, v: float = 1) -> None:
+        """Add ``v`` to counter ``field`` under the series' own lock, so a
+        caller needs no lock of its own (``+=`` on the property is a read
+        then a write)."""
+        self._series[field].inc(v)
+
     def counters(self) -> dict:
         return {f: getattr(self, f)
                 for f in {**self._COUNTERS, **self._GAUGES}}

@@ -35,11 +35,19 @@ loop (DESIGN.md §6):
 
 Results come back through :class:`Ticket` futures, scattered into the
 submit order of each ticket regardless of which flush group answered them.
+
+While ``telemetry.timeline`` is on, every stage below is also a host event
+on the JAX profiler's timeline (``serve.wait``, ``serve.stage``,
+``serve.dispatch``, ``serve.join``, ``serve.fetch``, ``serve.scatter``,
+``serve.observe`` on the loop's thread; ``serve.route`` and
+``serve.enqueue`` on the caller's), so a trace shows what the loop was
+doing while the device sat idle (DESIGN.md §12).
 """
 
 from __future__ import annotations
 
 import collections
+import math
 import threading
 import time
 
@@ -134,10 +142,11 @@ class _Flight:
 
     __slots__ = ("pin_cm", "eng", "gen", "key", "want_argmin", "entries",
                  "rows", "res", "t_launch", "bstats", "reason", "t_staged",
-                 "t_dispatched")
+                 "t_dispatched", "lag")
 
     def __init__(self, pin_cm, eng, gen, key, want_argmin, entries, rows,
-                 res, t_launch, bstats, reason, t_staged, t_dispatched):
+                 res, t_launch, bstats, reason, t_staged, t_dispatched,
+                 lag):
         self.pin_cm = pin_cm
         self.eng = eng
         self.gen = gen
@@ -151,6 +160,7 @@ class _Flight:
         self.reason = reason            # flush reason (span attribute)
         self.t_staged = t_staged        # stage -> dispatch boundary
         self.t_dispatched = t_dispatched
+        self.lag = lag                  # summed launch lag of its queries
 
 
 class CoalescingBatcher:
@@ -178,6 +188,7 @@ class CoalescingBatcher:
         self._queued = 0            # entries waiting in groups
         self._in_flight = 0         # entries staged/dispatched, not retired
         self._force = False         # flush() latch: ship everything queued
+        self._t_force = math.inf    # when the latch (or close) was set
         self._closing = False
         self._lock = make_lock("batcher.queue")
         self._cond = threading.Condition(self._lock)
@@ -199,6 +210,8 @@ class CoalescingBatcher:
         """Force every queued group to dispatch without waiting for the
         batch to fill or the deadline to expire."""
         with self._cond:
+            if not self._force:
+                self._t_force = time.perf_counter()
             self._force = True
             self._cond.notify_all()
 
@@ -223,6 +236,7 @@ class CoalescingBatcher:
             self.drain()
         with self._cond:
             self._closing = True
+            self._t_force = min(self._t_force, time.perf_counter())
             self._cond.notify_all()
         if self._thread is not None:
             self._thread.join(timeout=30.0)
@@ -240,6 +254,7 @@ class CoalescingBatcher:
         admission; the dispatch path revalidates them (see module doc).
         Blocks (or sheds) when the backpressure gate is closed.
         """
+        t_in = time.perf_counter()
         s = np.ascontiguousarray(np.asarray(s, np.float32)).reshape(-1, 2)
         t = np.ascontiguousarray(np.asarray(t, np.float32)).reshape(-1, 2)
         n = len(s)
@@ -251,11 +266,12 @@ class CoalescingBatcher:
         # head-sampling verdict, once per submit; traces materialize at
         # retire from group timestamps (nothing allocated here)
         sampled = tel.sampler.sample()
-        with self.server.engine.pin() as eng:
+        t_route = time.perf_counter()
+        with tel.span("serve.route"), self.server.engine.pin() as eng:
             gen = eng.generation
             keys = eng.buckets_of(s, t)
         now = time.perf_counter()
-        with self._cond:
+        with tel.span("serve.enqueue"), self._cond:
             if self._closing:
                 raise RuntimeError("batcher is closed")
             if self._queued + n > self.max_queue:
@@ -297,6 +313,10 @@ class CoalescingBatcher:
             stats.queue_depth_peak = max(stats.queue_depth_peak,
                                          self._queued)
             self._cond.notify_all()
+        # outside the queue lock, which the serve loop waits on
+        stats.inc("submit_calls")
+        stats.inc("route_seconds", now - t_route)
+        stats.inc("admit_seconds", time.perf_counter() - t_in)
         return ticket
 
     # ----------------------------------------------------------- serve loop
@@ -330,6 +350,7 @@ class CoalescingBatcher:
         exists or the batcher is closing with an empty queue."""
         bs = self.server.batch_size
         stats = self.server.stats
+        tel = self.server.telemetry
         with self._cond:
             while True:
                 best, reason = None, ""
@@ -350,6 +371,7 @@ class CoalescingBatcher:
                         best, reason = gk, r
                 if best is not None:
                     entries = self._groups[best]
+                    ready = self._ready_at(entries, bs)
                     chunk, rest = entries[:bs], entries[bs:]
                     if rest:
                         self._groups[best] = rest
@@ -357,6 +379,7 @@ class CoalescingBatcher:
                         del self._groups[best]
                         if not any(self._groups.values()):
                             self._force = False
+                            self._t_force = math.inf
                     self._queued -= len(chunk)
                     stats.queue_depth = self._queued
                     if reason == "full":
@@ -368,10 +391,23 @@ class CoalescingBatcher:
                     self._in_flight += len(chunk)
                     self._cond.notify_all()     # admission gate may reopen
                     gen, key, want_argmin = best
-                    return gen, key, want_argmin, chunk, reason
+                    return gen, key, want_argmin, chunk, reason, ready
                 if not block or (self._closing and not self._queued):
                     return None
-                self._cond.wait(timeout=self._wait_timeout(now))
+                with tel.span("serve.wait"):
+                    self._cond.wait(timeout=self._wait_timeout(now))
+
+    def _ready_at(self, entries: list, bs: int) -> float:
+        """When a group could first have been dispatched: its ``bs``-th
+        arrival (full), its oldest arrival plus the deadline, or the
+        flush/close latch once it holds an entry (forced) — whichever came
+        first.  Called under the queue lock."""
+        ready = entries[0].arrived + self.max_wait_s
+        if len(entries) >= bs:
+            ready = min(ready, entries[bs - 1].arrived)
+        if self._force or self._closing:
+            ready = min(ready, max(self._t_force, entries[0].arrived))
+        return ready
 
     def _wait_timeout(self, now: float) -> float:
         """Sleep until the nearest group deadline (bounded poll)."""
@@ -386,8 +422,12 @@ class CoalescingBatcher:
 
     # ------------------------------------------------------------- dispatch
     def _launch(self, gen: int, key: int, want_argmin: bool,
-                entries: list, reason: str) -> _Flight | None:
+                entries: list, reason: str, ready: float) -> _Flight | None:
         """Stage + dispatch one chunk under a pinned engine.
+
+        ``ready`` is when the chunk could first have been dispatched; each
+        query's launch lag runs from then, or from its own arrival if
+        later, to the launch.
 
         Returns the in-flight handle, or None when the chunk's generation
         was superseded before dispatch — its entries are re-routed under
@@ -396,6 +436,7 @@ class CoalescingBatcher:
         """
         srv = self.server
         stats = srv.stats
+        tel = srv.telemetry
         cm = srv.engine.pin()
         eng = cm.__enter__()
         if eng.generation != gen:
@@ -412,14 +453,18 @@ class CoalescingBatcher:
         rows = srv.batch_size if getattr(eng, "static_shapes", True) else n
         sb = np.zeros((rows, 2), np.float32)
         tb = np.zeros((rows, 2), np.float32)
+        ready_sum = 0.0
         for i, e in enumerate(entries):
             sb[i] = e.s
             tb[i] = e.t
+            ready_sum += max(ready, e.arrived)
         t0 = time.perf_counter()
-        staged = eng.stage(sb, tb, bucket=key)
+        with tel.span("serve.stage"):
+            staged = eng.stage(sb, tb, bucket=key)
         t_staged = time.perf_counter()
-        res = eng.dispatch_staged(staged, bucket=key,
-                                  want_argmin=want_argmin)
+        with tel.span("serve.dispatch"):
+            res = eng.dispatch_staged(staged, bucket=key,
+                                      want_argmin=want_argmin)
         t_dispatched = time.perf_counter()
         bstats = srv._bucket_stats(key, eng)
         bstats.batches += 1
@@ -430,7 +475,8 @@ class CoalescingBatcher:
             bstats.deadline_flushes += 1
         stats.batches += 1
         return _Flight(cm, eng, gen, key, want_argmin, entries, rows, res,
-                       t0, bstats, reason, t_staged, t_dispatched)
+                       t0, bstats, reason, t_staged, t_dispatched,
+                       n * t0 - ready_sum)
 
     def _requeue(self, entries: list, want_argmin: bool,
                  old_gen: int = -1) -> None:
@@ -461,42 +507,52 @@ class CoalescingBatcher:
         close out stats, release the generation pin."""
         srv = self.server
         stats = srv.stats
+        tel = srv.telemetry
         try:
             t_retire = time.perf_counter()
-            jax.block_until_ready(f.res)
+            with tel.span("serve.join"):
+                jax.block_until_ready(f.res)
             t_joined = time.perf_counter()
             dt = t_joined - f.t_launch
             n = len(f.entries)
-            outs = [np.asarray(r)[:n] for r in f.res]
-            per_ticket: dict = collections.defaultdict(lambda: ([], []))
-            for bi, e in enumerate(f.entries):
-                rows, slots = per_ticket[e.ticket]
-                rows.append(bi)
-                slots.append(e.slot)
-            for ticket, (rows, slots) in per_ticket.items():
-                ridx = np.asarray(rows)
-                ticket._write(np.asarray(slots),
-                              [o[ridx] for o in outs])
+            with tel.span("serve.fetch"):
+                outs = [np.asarray(r)[:n] for r in f.res]
+            with tel.span("serve.scatter"):
+                # counted before any ticket completes: a caller that reads
+                # the stats after result() sees its own queries
+                f.bstats.queries += n
+                f.bstats.seconds += dt
+                stats.queries += n
+                stats.seconds += dt
+                stats.inc("launch_lag_seconds", f.lag)
+                per_ticket: dict = collections.defaultdict(lambda: ([], []))
+                for bi, e in enumerate(f.entries):
+                    rows, slots = per_ticket[e.ticket]
+                    rows.append(bi)
+                    slots.append(e.slot)
+                for ticket, (rows, slots) in per_ticket.items():
+                    ridx = np.asarray(rows)
+                    ticket._write(np.asarray(slots),
+                                  [o[ridx] for o in outs])
             t_reply = time.perf_counter()
-            self._observe(f, per_ticket, t_retire, t_joined, t_reply)
-            f.bstats.queries += n
-            f.bstats.seconds += dt
-            stats.queries += n
-            stats.seconds += dt
-            if srv.engine.generation != f.gen:
-                # a swap published while this group was in flight: it
-                # finished on its pinned (now superseded) artifact
-                stats.stale_batches += 1
-            note = getattr(f.eng, "note_batch_seconds", None)
-            if note is not None:
-                note(f.key, dt)
-            shard_stats = getattr(f.eng, "shard_stats", None)
-            if shard_stats is not None:
-                stats.per_shard = shard_stats()
-            if srv._recorder is not None:
-                s = np.stack([e.s for e in f.entries])
-                t = np.stack([e.t for e in f.entries])
-                srv._recorder.record(s, t)
+            with tel.span("serve.observe"):
+                self._observe(f, per_ticket, t_retire, t_joined, t_reply)
+                if srv.engine.generation != f.gen:
+                    # a swap published while this group was in flight: it
+                    # finished on its pinned (now superseded) artifact
+                    stats.stale_batches += 1
+                note = getattr(f.eng, "note_batch_seconds", None)
+                if note is not None:
+                    note(f.key, dt)
+                shard_stats = getattr(f.eng, "shard_stats", None)
+                if shard_stats is not None:
+                    stats.per_shard = shard_stats()
+                if srv._recorder is not None:
+                    s = np.stack([e.s for e in f.entries])
+                    t = np.stack([e.t for e in f.entries])
+                    srv._recorder.record(s, t)
+                stats.inc("retired_batches")
+                stats.inc("retire_seconds", time.perf_counter() - t_joined)
         finally:
             f.pin_cm.__exit__(None, None, None)
             with self._cond:
@@ -545,10 +601,4 @@ class CoalescingBatcher:
             tr.stage("queue_wait", f.t_launch - ents[0].arrived)
             for name, dur in stages[1:]:
                 tr.stage(name, dur)
-            # rescue is fused into dispatch/device_join by the quantized
-            # engines (engine-side counters cover it); unwind only happens
-            # on the sync query_paths span — present as explicit zeros so
-            # the tree is complete
-            tr.stage("rescue", 0.0)
-            tr.stage("unwind", 0.0)
             srv_tel.spans.add(tr.close(ticket.t_submit, t_reply))

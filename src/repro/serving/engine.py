@@ -97,6 +97,16 @@ class ServeStats(obs.StatsView):
         "deadline_flushes": ("serve_deadline_flushes_total", int),
         "forced_flushes": ("serve_forced_flushes_total", int),
         "requeued_batches": ("serve_requeued_batches_total", int),
+        # where the batcher's time goes (DESIGN.md §12): each query's wait
+        # from when its group could be dispatched to its launch; the serve
+        # loop's host time per retired batch; submit() calls that admitted
+        # their queries, their whole time and the routing part of it
+        "launch_lag_seconds": ("serve_launch_lag_seconds_total", float),
+        "retire_seconds": ("serve_retire_seconds_total", float),
+        "retired_batches": ("serve_retired_batches_total", int),
+        "submit_calls": ("serve_submit_calls_total", int),
+        "admit_seconds": ("serve_admit_seconds_total", float),
+        "route_seconds": ("serve_route_seconds_total", float),
     }
     _GAUGES = {
         # generation the last request was served on; per_bucket is reset

@@ -1,14 +1,19 @@
 """Telemetry subsystem (DESIGN.md §12): histogram quantile correctness,
 request-span completeness through the serving stack, generation-tagged
-series reset across hot-swaps, and export fidelity.
+series reset across hot-swaps, export fidelity, the serve loop's
+``serve.*`` spans on the profiler timeline and the batcher's launch-lag,
+retire and admission counters.
 
 The serving-path tests drive a private ``MetricsRegistry`` per server (the
 views accept one), so nothing here depends on — or pollutes — the
 process-wide ``obs.REGISTRY`` other tests record into.
 """
 
+import glob
 import json
+import time
 
+import jax
 import numpy as np
 import pytest
 
@@ -141,7 +146,10 @@ def test_async_spans_complete_and_telescope():
         assert tr.e2e_seconds > 0
         assert abs(tr.stage_sum - tr.e2e_seconds) <= 0.05 * tr.e2e_seconds
         tree = tr.tree()
-        assert [c["name"] for c in tree["children"]] == list(obs.ASYNC_STAGES)
+        # the async path has no rescue or unwind stage (sync spans do)
+        assert [c["name"] for c in tree["children"]] == [
+            "admission", "queue_wait", "stage", "dispatch", "pipeline_wait",
+            "device_join", "reply"]
         assert tree["attrs"]["outcome"] == "ok"
     # stage latency histograms saw every retired group
     for st in ("queue_wait", "device_join", "reply"):
@@ -188,6 +196,7 @@ def test_shed_request_traced_with_shed_outcome():
             if t.attrs["outcome"] == "shed"]
     assert len(shed) == 1
     assert shed[0].closed and shed[0].complete(obs.ASYNC_STAGES)
+    assert set(shed[0].stages) == set(obs.ASYNC_STAGES)
     (ev,) = tel.events.events("shed")
     assert ev["n"] == 3 and ev["max_queue"] == 4
     assert srv.stats.shed == 3
@@ -287,3 +296,133 @@ def test_event_log_ring_and_jsonl(tmp_path):
     ev.enabled = False
     assert ev.emit("swap") is None
     assert ev.counts() == {"shed": 4}
+
+
+# ------------------------------------------- serve loop on the profiler clock
+
+LOOP_STAGES = ["serve.stage", "serve.dispatch", "serve.join", "serve.fetch",
+               "serve.scatter", "serve.observe"]
+
+
+def _serve_lines(tmp_path, timeline):
+    """One full group served under a CPU profiler session; the ``serve.*``
+    host events of each profiler line that has any, in time order."""
+    srv, tel = _traced_server(_KeyedEngine(), batch_size=8)
+    tel.timeline = timeline
+    b = CoalescingBatcher(srv, autostart=False, max_wait_ms=60_000)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        b.start()
+        time.sleep(0.05)                # the loop waits on an empty queue
+        xs = np.full(8, 4.0) + np.arange(8) * 4
+        b.submit(_pts(xs), _pts(xs)).result(timeout=10)
+        b.close()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                            / "*.xplane.pb"))
+    lines = []
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        for ln in plane.lines:
+            evs = sorted((ev.start_ns, ev.start_ns + ev.duration_ns,
+                          ev.name) for ev in ln.events
+                         if ev.name.startswith("serve."))
+            if evs:
+                lines.append(evs)
+    return lines
+
+
+def test_timeline_spans_land_on_loop_and_caller_lines(tmp_path):
+    lines = _serve_lines(tmp_path, timeline=True)
+    assert len(lines) == 2
+    (loop,) = [ln for ln in lines if any(e[2] == "serve.wait" for e in ln)]
+    (caller,) = [ln for ln in lines if ln is not loop]
+    assert [e[2] for e in caller] == ["serve.route", "serve.enqueue"]
+    names = [e[2] for e in loop]
+    collapsed = [n for i, n in enumerate(names)
+                 if i == 0 or n != names[i - 1]]
+    # waits on the empty queue, one group through the pipeline, then at
+    # most the waits before close
+    assert collapsed[:7] == ["serve.wait"] + LOOP_STAGES
+    assert set(collapsed[7:]) <= {"serve.wait"}
+    for ln in (loop, caller):           # one thread: spans never overlap
+        assert all(a[1] <= b[0] for a, b in zip(ln, ln[1:]))
+
+
+def test_timeline_off_puts_no_serve_event_on_the_trace(tmp_path):
+    assert _serve_lines(tmp_path, timeline=False) == []
+
+
+@pytest.mark.parametrize("reason", ["full", "deadline", "forced"])
+def test_launch_lag_runs_from_when_the_group_could_ship(reason):
+    """Each query's lag runs from when its group could first ship (the
+    batch_size-th arrival, the oldest arrival plus the deadline, or the
+    flush latch), or from its own later arrival, to the launch: never
+    negative, never more than its queue wait."""
+    srv, tel = _traced_server(_KeyedEngine(), batch_size=8)
+    wait_ms = 1.0 if reason == "deadline" else 60_000
+    b = CoalescingBatcher(srv, autostart=False, max_wait_ms=wait_ms)
+    n = 8 if reason == "full" else 3
+    tickets = []
+    for i in range(n):                  # one key-0 query per submit
+        tickets.append(b.submit(_pts([4.0 * i]), _pts([4.0 * i])))
+        time.sleep(0.002)
+    if reason == "forced":
+        before = time.perf_counter()
+        b.flush()
+        after = time.perf_counter()
+    time.sleep(0.01)
+    b.start()
+    for tk in tickets:
+        tk.result(timeout=10)
+    b.close()
+    st = srv.stats
+    assert st.batches == 1 and getattr(st, f"{reason}_flushes") == 1
+    traces = sorted(tel.spans.traces("async"), key=lambda tr: tr.t_start)
+    arrived = [tr.t_start + tr.stages["admission"] for tr in traces]
+    waits = [tr.stages["queue_wait"] for tr in traces]
+    launch = arrived[0] + waits[0]
+    lag = st.launch_lag_seconds
+
+    def lag_from(ready):
+        return sum(launch - max(ready, a) for a in arrived)
+
+    assert 0.0 <= lag <= sum(waits)
+    if reason == "full":
+        assert lag == pytest.approx(n * (launch - arrived[-1]), abs=1e-6)
+    elif reason == "deadline":
+        assert lag == pytest.approx(lag_from(arrived[0] + 1e-3), abs=1e-6)
+    else:
+        assert lag_from(after) - 1e-6 <= lag <= lag_from(before) + 1e-6
+
+
+def test_admission_counters_count_calls_and_time_routing():
+    srv, _ = _traced_server(_KeyedEngine(), batch_size=8)
+    b = CoalescingBatcher(srv, autostart=False)
+    sizes = [1, 3, 5, 2]
+    for k in sizes:
+        xs = np.arange(k, dtype=np.float32)
+        b.submit(_pts(xs), _pts(xs))
+    st = srv.stats
+    assert st.submit_calls == len(sizes)
+    assert st.submitted == sum(sizes)
+    assert 0.0 < st.route_seconds <= st.admit_seconds
+    b.start()
+    assert b.drain(timeout=10)
+    b.close()
+
+
+def test_retired_batches_equal_batches_after_drain():
+    srv, _ = _traced_server(_KeyedEngine(), batch_size=8)
+    b = CoalescingBatcher(srv, autostart=True, max_wait_ms=1.0)
+    rng = np.random.default_rng(4)
+    for _ in range(12):
+        xs = rng.integers(0, 64, size=5).astype(np.float32)
+        b.submit(_pts(xs), _pts(xs))
+    assert b.drain(timeout=10)
+    b.close()
+    st = srv.stats
+    assert st.batches > 1
+    assert st.retired_batches == st.batches
+    assert st.retire_seconds > 0.0
+    assert st.launch_lag_seconds >= 0.0
