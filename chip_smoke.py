@@ -175,12 +175,12 @@ def assert_compiled(smoke: Smoke, bx, batch: int) -> None:
     import jax.numpy as jnp
     from repro.core.packed import _fold_endpoint, _join_endpoints
 
-    z = jnp.zeros((batch, 2), jnp.float32)
+    pts = jnp.zeros((2, batch, 2), jnp.float32)     # both endpoint sides
     for k, w in enumerate(bx.widths):
-        fold = _fold_endpoint.jit.lower(bx, z, bucket=k, use_kernels=True)
-        ms = jax.eval_shape(functools.partial(
-            _fold_endpoint.jit, bucket=k, use_kernels=True), bx, z)
-        join = _join_endpoints.jit.lower(bx, ms, ms, z, z, use_kernels=True)
+        fold = _fold_endpoint.jit.lower(bx, pts, bucket=k, use_kernels=True)
+        ms, mt = jax.eval_shape(functools.partial(
+            _fold_endpoint.jit, bucket=k, use_kernels=True), bx, pts)
+        join = _join_endpoints.jit.lower(bx, ms, mt, pts, use_kernels=True)
         ok = all("tpu_custom_call" in low.as_text() for low in (fold, join))
         smoke.check(ok, f"pallas bucket {k} (width {w}): fold and join "
                         "lower to tpu_custom_call")

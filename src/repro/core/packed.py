@@ -1099,10 +1099,30 @@ def _edges_of(idx) -> tuple:
     return (idx.edges_a, idx.edges_b, idx.edges_c, idx.grid)
 
 
+def stack_endpoints(s, t) -> jnp.ndarray:
+    """Both endpoint sides of a batch as one [2, B, 2] float32 device array.
+
+    Host inputs are stacked on the host and cross in one transfer (one
+    ``jax.device_put``); inputs already on the device are stacked there.
+    :func:`query_stacked` folds and joins both sides from this one array.
+    """
+    if isinstance(s, jax.Array) or isinstance(t, jax.Array):
+        return jnp.stack([jnp.asarray(s, jnp.float32),
+                          jnp.asarray(t, jnp.float32)])
+    return jax.device_put(np.stack([np.asarray(s, np.float32),
+                                    np.asarray(t, np.float32)]))
+
+
 @_jit_entry("fold_endpoint", static_argnames=("bucket", "use_kernels"))
 def _fold_endpoint(idx, pts: jnp.ndarray, bucket=None,
                    use_kernels: bool = False):
-    """locate + gather + visibility-fold one endpoint side (own jit entry).
+    """locate + gather + visibility-fold both endpoint sides (own jit entry).
+
+    ``pts`` is the [2, B, 2] stack of the s and t sides
+    (:func:`stack_endpoints`); returns the (s, t) pair of masked triples.
+    Every row folds on its own (locate, gather, visibility mask), so the
+    2*B endpoints fold as one flat batch and the masked planes split back
+    into halves — the same numbers as one fold per side, from one program.
 
     ``bucket=None`` gathers the single PackedIndex slab; an int gathers the
     bucketed layout at that dispatch bucket.  Splitting the fold from the
@@ -1116,56 +1136,72 @@ def _fold_endpoint(idx, pts: jnp.ndarray, bucket=None,
     to the fused engine.
     """
     TRACES.bump("fold_endpoint")
-    pts = pts.astype(jnp.float32)
-    r = locate_regions(idx, pts)
+    B = pts.shape[1]
+    flat = pts.reshape(2 * B, 2)
+    r = locate_regions(idx, flat)
     labels = (_gather_packed(idx, r) if bucket is None
               else _gather_bucketed(idx, r, bucket))
-    return _mask_labels(labels, pts, _edges_of(idx), use_kernels)
+    masked = [m.reshape(2, B, -1)
+              for m in _mask_labels(labels, flat, _edges_of(idx),
+                                    use_kernels)]
+    return tuple(m[0] for m in masked), tuple(m[1] for m in masked)
 
 
 @_jit_entry("join_endpoints", static_argnames=("use_kernels", "want_argmin"))
-def _join_endpoints(idx, masked_s, masked_t, s: jnp.ndarray, t: jnp.ndarray,
-                    use_kernels: bool = False, want_argmin: bool = False,
-                    qerr2=None):
-    """Co-visibility + Eq. 1-3 join over folded endpoint sides (jit entry)."""
+def _join_endpoints(idx, masked_s, masked_t, pts: jnp.ndarray,
+                    use_kernels: bool = False, want_argmin: bool = False):
+    """Co-visibility + Eq. 1-3 join over folded endpoint sides (jit entry).
+
+    ``pts`` is the same [2, B, 2] stack the fold read.  Quantized argmin
+    joins also flag ambiguous rows against the summed per-side error
+    bound (:func:`_join_masked`)."""
     TRACES.bump("join_endpoints")
-    s = s.astype(jnp.float32)
-    t = t.astype(jnp.float32)
+    s, t = pts[0], pts[1]
     covis = _segvis(s, t, _edges_of(idx), use_kernels)
+    qerr2 = (idx.qerr + idx.qerr
+             if want_argmin and idx.layout.quantized else None)
     return _join_masked(masked_s, masked_t, s, t, covis, use_kernels,
                         want_argmin, qerr2=qerr2)
 
 
-def query_batch(idx: PackedIndex, s: jnp.ndarray, t: jnp.ndarray,
+def query_stacked(idx, pts: jnp.ndarray, bucket: int | None = None,
+                  use_kernels: bool = False, want_argmin: bool = False):
+    """Eq. 1-3 over a stacked [2, B, 2] batch: two async jit dispatches.
+
+    One ``fold_endpoint`` folds both endpoint sides and one
+    ``join_endpoints`` joins them (see :func:`_fold_endpoint`); ``pts``
+    comes from :func:`stack_endpoints`.  ``bucket``: None for the single
+    PackedIndex slab, else the bucketed layout's dispatch bucket.  With
+    ``want_argmin`` the result carries the winning (covis, via_s, hub,
+    via_t) ids; quantized layouts add a sixth ``amb`` array — rows the
+    caller must rescue against the residual (:func:`rescue_exact`).
+    """
+    ms, mt = _fold_endpoint(idx, pts, bucket=bucket, use_kernels=use_kernels)
+    return _join_endpoints(idx, ms, mt, pts, use_kernels=use_kernels,
+                           want_argmin=want_argmin)
+
+
+def query_batch(idx: PackedIndex, s, t,
                 use_kernels: bool = False) -> jnp.ndarray:
     """Batched Eq. 1-3: shortest distances for query pairs [B,2]x[B,2].
 
     use_kernels=True routes visibility + join through the Pallas kernels
     (``repro.kernels.ops``); False uses their jnp references — identical
-    semantics, asserted by tests.  Two async jit dispatches per call
-    (endpoint folds + join; see :func:`_fold_endpoint`).
+    semantics, asserted by tests.  One transfer of both endpoint sides,
+    then :func:`query_stacked`'s fold and join dispatches.
     """
-    s = jnp.asarray(s).astype(jnp.float32)
-    t = jnp.asarray(t).astype(jnp.float32)
-    ms = _fold_endpoint(idx, s, use_kernels=use_kernels)
-    mt = _fold_endpoint(idx, t, use_kernels=use_kernels)
-    return _join_endpoints(idx, ms, mt, s, t, use_kernels=use_kernels)
+    return query_stacked(idx, stack_endpoints(s, t), use_kernels=use_kernels)
 
 
-def query_batch_argmin(idx: PackedIndex, s: jnp.ndarray, t: jnp.ndarray,
-                       use_kernels: bool = False):
+def query_batch_argmin(idx: PackedIndex, s, t, use_kernels: bool = False):
     """Distances + winning (via_s, hub, via_t) label ids (path unwinding).
 
+    Same single transfer and two dispatches as :func:`query_batch`.
     Quantized layouts return a sixth ``amb`` array — rows the caller must
     rescue against the residual (:func:`rescue_exact`) for exact argmin.
     """
-    s = jnp.asarray(s).astype(jnp.float32)
-    t = jnp.asarray(t).astype(jnp.float32)
-    ms = _fold_endpoint(idx, s, use_kernels=use_kernels)
-    mt = _fold_endpoint(idx, t, use_kernels=use_kernels)
-    qerr2 = idx.qerr + idx.qerr if idx.layout.quantized else None
-    return _join_endpoints(idx, ms, mt, s, t, use_kernels=use_kernels,
-                           want_argmin=True, qerr2=qerr2)
+    return query_stacked(idx, stack_endpoints(s, t), use_kernels=use_kernels,
+                         want_argmin=True)
 
 
 # ---------------------------------------------------------------------------
@@ -1235,24 +1271,20 @@ def _gather_bucketed(bx: BucketedIndex, regions: jnp.ndarray, bucket: int,
     return jax.lax.optimization_barrier((hub, xy, vd, vid))
 
 
-def query_batch_at_bucket(bx: BucketedIndex, s: jnp.ndarray, t: jnp.ndarray,
-                          bucket: int, use_kernels: bool = False,
+def query_batch_at_bucket(bx: BucketedIndex, s, t, bucket: int,
+                          use_kernels: bool = False,
                           want_argmin: bool = False):
     """Eq. 1-3 over one dispatch bucket (per-bucket fold + join jit entries).
 
     Every query's endpoint regions must live in buckets <= ``bucket``
     (i.e. ``bucket == max(endpoint buckets)`` after routing); the result is
     then bitwise-identical to the full-width ``query_batch`` because the
-    extra slots it would have carried are all inf/HUB_PAD padding.
+    extra slots it would have carried are all inf/HUB_PAD padding.  One
+    transfer of both endpoint sides, one fold and one join dispatch
+    (:func:`query_stacked`).
     """
-    s = jnp.asarray(s).astype(jnp.float32)
-    t = jnp.asarray(t).astype(jnp.float32)
-    ms = _fold_endpoint(bx, s, bucket=bucket, use_kernels=use_kernels)
-    mt = _fold_endpoint(bx, t, bucket=bucket, use_kernels=use_kernels)
-    qerr2 = (bx.qerr + bx.qerr
-             if bx.layout.quantized and want_argmin else None)
-    return _join_endpoints(bx, ms, mt, s, t, use_kernels=use_kernels,
-                           want_argmin=want_argmin, qerr2=qerr2)
+    return query_stacked(bx, stack_endpoints(s, t), bucket=bucket,
+                         use_kernels=use_kernels, want_argmin=want_argmin)
 
 
 # ---------------------------------------------------------------------------
@@ -1764,8 +1796,8 @@ def query_batch_bucketed(bx: BucketedIndex, s, t,
     outs = empty_results(n, want_argmin)
     for k in np.unique(buckets):
         m = buckets == k
-        res = query_batch_at_bucket(bx, jnp.asarray(s[m]), jnp.asarray(t[m]),
-                                    bucket=int(k), use_kernels=use_kernels,
+        res = query_batch_at_bucket(bx, s[m], t[m], bucket=int(k),
+                                    use_kernels=use_kernels,
                                     want_argmin=want_argmin)
         if want_argmin and bx.layout.quantized:
             # 6-tuple: rescue ambiguous-margin rows against the residual

@@ -298,15 +298,13 @@ class PathServer:
                     tb = np.zeros((rows, 2), np.float32)
                     sb[:len(sel)] = s[sel]
                     tb[:len(sel)] = t[sel]
-                    sj, tj = (jnp.asarray(sb), jnp.asarray(tb)) if pad \
-                        else (sb, tb)
                     if self._sharding is not None:
-                        sj = jax.device_put(sj, self._sharding)
-                        tj = jax.device_put(tj, self._sharding)
+                        sb = jax.device_put(sb, self._sharding)
+                        tb = jax.device_put(tb, self._sharding)
                     if want_argmin:
-                        res = eng.batch_argmin(sj, tj, bucket=int(k))
+                        res = eng.batch_argmin(sb, tb, bucket=int(k))
                     else:
-                        res = (eng.batch(sj, tj, bucket=int(k)),)
+                        res = (eng.batch(sb, tb, bucket=int(k)),)
                     for o, r in zip(outs, res):
                         o[sel] = np.asarray(r)[:len(sel)]
                     bstats.batches += 1

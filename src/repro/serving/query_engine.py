@@ -32,9 +32,8 @@ from repro import obs
 from repro.core.grid import EHLIndex
 from repro.core.packed import (BucketedIndex, LAYOUT_F32, PackedIndex,
                                gather_masked_exact, join_masked,
-                               pack_bucketed, query_batch,
-                               query_batch_argmin, query_batch_at_bucket,
-                               rescue_exact, splice_rescue)
+                               pack_bucketed, query_stacked,
+                               rescue_exact, splice_rescue, stack_endpoints)
 from repro.core.query import query as host_query
 
 
@@ -168,21 +167,15 @@ class DeviceEngine(QueryEngine):
             return np.zeros(len(s), dtype=np.int32)
         return np.maximum(self._route(s), self._route(t)).astype(np.int32)
 
-    def _run(self, s, t, bucket: int, want_argmin: bool):
-        s = jnp.asarray(s, jnp.float32)
-        t = jnp.asarray(t, jnp.float32)
-        if self.bucketed:
-            return query_batch_at_bucket(self.index, s, t, bucket=bucket,
-                                         use_kernels=self.use_kernels,
-                                         want_argmin=want_argmin)
-        fn = query_batch_argmin if want_argmin else query_batch
-        return fn(self.index, s, t, use_kernels=self.use_kernels)
+    def _run(self, pts, bucket: int, want_argmin: bool):
+        """Launch a staged batch: one fold and one join dispatch."""
+        return query_stacked(self.index, pts,
+                             bucket=bucket if self.bucketed else None,
+                             use_kernels=self.use_kernels,
+                             want_argmin=want_argmin)
 
-    def batch(self, s, t, bucket: int = 0) -> np.ndarray:
-        return self._run(s, t, bucket, want_argmin=False)
-
-    def batch_argmin(self, s, t, bucket: int = 0):
-        res = self._run(s, t, bucket, want_argmin=True)
+    def _argmin(self, pts, bucket: int):
+        res = self._run(pts, bucket, want_argmin=True)
         if not self.quantized:
             return res
         # quantized: 6-tuple — rescue ambiguous-margin rows against the
@@ -190,6 +183,8 @@ class DeviceEngine(QueryEngine):
         # repolint: disable=hot-path-sync -- documented rescue trigger: one flag word, the exactness contract pays this sync
         if bool(np.asarray(res[5]).any()):
             with obs.Stopwatch() as sw:
+                # repolint: disable=hot-path-sync -- the rescue re-reads the batch's endpoints on the host (already synced above)
+                s, t = np.asarray(pts)
                 exact = rescue_exact(self.index, s, t,
                                      self.bucket_width(bucket), res[1],
                                      use_kernels=self.use_kernels)
@@ -205,33 +200,52 @@ class DeviceEngine(QueryEngine):
         # repolint: disable=hot-path-sync -- batch_argmin is the synchronous API; host results are its contract
         return tuple(np.asarray(r) for r in res[:5])
 
+    def batch(self, s, t, bucket: int = 0) -> np.ndarray:
+        return self._run(self.stage(s, t), bucket, want_argmin=False)
+
+    def batch_argmin(self, s, t, bucket: int = 0):
+        return self._argmin(self.stage(s, t), bucket)
+
     def stage(self, s, t, bucket: int = 0):
-        """Start the host->device copies for a batch (jax transfers are
-        async; on accelerators the DMA overlaps the in-flight batch)."""
-        return (jnp.asarray(s, jnp.float32), jnp.asarray(t, jnp.float32))
+        """Stack both endpoint sides into one [2, B, 2] float32 array and
+        start its one host->device copy (jax transfers are async; on
+        accelerators the DMA overlaps the in-flight batch)."""
+        return stack_endpoints(s, t)
+
+    def dispatch_staged(self, staged, bucket: int = 0,
+                        want_argmin: bool = False) -> tuple:
+        """Fold and join dispatches over the staged array, unsynchronized
+        (quantized argmin batches sync for their rescue)."""
+        if want_argmin:
+            return tuple(self._argmin(staged, bucket))
+        return (self._run(staged, bucket, want_argmin=False),)
 
     def warmup(self, batch_size: int, want_argmin: bool = False) -> None:
         """Trace every per-bucket jit entry once with the serving shape.
 
-        ``want_argmin=True`` additionally traces the argmin (path
-        extraction) entries — they are separate jit cache entries, so
-        without this the first ``query_paths`` batch pays XLA compile
-        inside the timed serving loop.
+        Batches are staged as the serve loop stages them, so warm-up traces
+        exactly the programs the loop runs.  ``want_argmin=True``
+        additionally traces the argmin (path extraction) entries — they are
+        separate jit cache entries, so without this the first
+        ``query_paths`` batch pays XLA compile inside the timed serving
+        loop.
         """
-        z = jnp.zeros((batch_size, 2), jnp.float32)
+        z = np.zeros((batch_size, 2), np.float32)
+        pts = self.stage(z, z)
         for b in range(self.num_buckets):
-            self._run(z, z, b, want_argmin=False).block_until_ready()
+            self._run(pts, b, want_argmin=False).block_until_ready()
             if want_argmin:
-                jax.block_until_ready(self._run(z, z, b, want_argmin=True))
+                jax.block_until_ready(self._run(pts, b, want_argmin=True))
                 if self.quantized:
                     # the rescue path's entries (exact gather + plain
                     # argmin join) are their own jit cache entries
                     W = self.bucket_width(b)
+                    zj = jnp.asarray(z)
                     d0 = jnp.full((batch_size, W), jnp.inf, jnp.float32)
-                    ms = gather_masked_exact(self.index, z, d0, W,
+                    ms = gather_masked_exact(self.index, zj, d0, W,
                                              use_kernels=self.use_kernels)
                     jax.block_until_ready(join_masked(
-                        ms, ms, z, z, jnp.zeros(batch_size, bool),
+                        ms, ms, zj, zj, jnp.zeros(batch_size, bool),
                         use_kernels=self.use_kernels, want_argmin=True))
 
     def device_bytes(self) -> int:

@@ -299,3 +299,54 @@ def test_async_argmin_matches_sync_bitwise(real_server, queries_s):
     assert len(got) == 5
     for r, g in zip(ref, got):
         np.testing.assert_array_equal(np.asarray(r), np.asarray(g))
+
+
+@pytest.mark.parametrize("want_argmin", [False, True],
+                         ids=["dist", "argmin"])
+def test_launch_is_one_transfer_and_two_programs(real_server, queries_s,
+                                                 monkeypatch, want_argmin):
+    """One ``_launch`` stages both endpoint sides in one host->device
+    transfer and dispatches one fold and one join — nothing else crosses
+    to the device (implicit transfers are refused), and the launched batch
+    answers bitwise what the synchronous path answers."""
+    import collections
+
+    import jax
+    from repro.core import packed
+
+    srv = real_server
+    s = queries_s.s.astype(np.float32)
+    t = queries_s.t.astype(np.float32)
+    keys = srv.engine.buckets_of(s, t)
+    m = keys == keys[0]                 # one routing key: one chunk
+    s, t = s[m], t[m]
+    b = CoalescingBatcher(srv, autostart=False)
+    tk = b.submit(s, t, want_argmin=want_argmin)
+    b.flush()
+    chunk = b._pop_ready(block=False)
+    assert chunk is not None and len(chunk[3]) == len(s)
+
+    calls = collections.Counter()
+
+    def counted(name, fn):
+        def wrapper(*args, **kw):
+            calls[name] += 1
+            return fn(*args, **kw)
+        return wrapper
+
+    monkeypatch.setattr(jax, "device_put",
+                        counted("device_put", jax.device_put))
+    for fn in (packed._fold_endpoint, packed._join_endpoints):
+        monkeypatch.setattr(packed, fn.__name__, counted(fn.entry, fn))
+    with jax.transfer_guard_host_to_device("disallow"):
+        flight = b._launch(*chunk)
+    assert calls == {"device_put": 1, "fold_endpoint": 1,
+                     "join_endpoints": 1}
+    monkeypatch.undo()
+    b._retire(flight)
+    got = tk.result(timeout=1)
+    ref = srv._dispatch(s, t, want_argmin=want_argmin)
+    assert len(ref) == (5 if want_argmin else 1)
+    for r, g in zip(ref, got if want_argmin else (got,)):
+        np.testing.assert_array_equal(np.asarray(r), np.asarray(g))
+    b.close(drain=False)

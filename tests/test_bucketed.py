@@ -8,6 +8,8 @@ specific properties:
 * bucket-width/slot accounting consistency and the device-byte win over
   the single slab (plus exact analytic estimators);
 * bucket dispatch covers every query and agrees with the per-bucket entry;
+* the stacked launch: one ``fold_endpoint`` over both endpoint sides is
+  row-independent, and the staged path equals the synchronous one;
 * PathServer bucket routing + batched path extraction over the engines.
 """
 
@@ -128,3 +130,50 @@ def test_path_server_paths_are_optimal(compressed, queries_s):
             assert abs(path_length(p) - di) < 1e-3
         else:
             assert p == []
+
+
+HOST_TOL = 1e-4      # f32 engine vs f64 oracle (test_conformance.py)
+BUCKETS = (0, 1)     # the lattice index's whole bucket ladder (128, 256)
+
+
+@pytest.mark.parametrize("want_argmin", [False, True],
+                         ids=["dist", "argmin"])
+@pytest.mark.parametrize("layout", ["f32", "bf16"])
+@pytest.mark.parametrize("bucket", BUCKETS)
+def test_stacked_fold_is_row_independent(conformance, bucket, layout,
+                                         want_argmin):
+    """One fold over the stacked [s; t] batch is, half for half and bit for
+    bit, the fold of [s; s] and of [t; t]; a staged launch equals the
+    synchronous batch bitwise; and the answers match the host oracle."""
+    from repro.core.packed import _fold_endpoint, stack_endpoints
+    from repro.serving.query_engine import JnpEngine
+
+    bx = conformance.bucketed(layout)
+    assert bx.num_buckets == len(BUCKETS)
+    eng = JnpEngine(bx)
+    s, t = conformance.s, conformance.t
+
+    ms, mt = _fold_endpoint(bx, stack_endpoints(s, t), bucket=bucket)
+    ss, _ = _fold_endpoint(bx, stack_endpoints(s, s), bucket=bucket)
+    _, tt = _fold_endpoint(bx, stack_endpoints(t, t), bucket=bucket)
+    for got, want in zip(ms + mt, ss + tt):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    # the dispatch contract: every endpoint lives in a bucket <= the batch's
+    m = eng.buckets_of(s, t) <= bucket
+    assert m.any()
+    sb, tb = s[m], t[m]
+    staged = eng.dispatch_staged(eng.stage(sb, tb), bucket=bucket,
+                                 want_argmin=want_argmin)
+    sync = (eng.batch_argmin(sb, tb, bucket=bucket) if want_argmin
+            else (eng.batch(sb, tb, bucket=bucket),))
+    assert len(staged) == len(sync) == (5 if want_argmin else 1)
+    for got, want in zip(staged, sync):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    d = np.asarray(staged[0])
+    truth = conformance.run("host", "f32")[0][m]
+    fin = np.isfinite(truth)
+    assert np.array_equal(fin, np.isfinite(d))
+    np.testing.assert_allclose(d[fin], truth[fin], rtol=HOST_TOL,
+                               atol=HOST_TOL + 2.0 * conformance.qerr(layout))
