@@ -43,7 +43,7 @@ class _Cell:
         self.chips = 1
 
 
-def sweep(server, mp, cell, rates, seconds, seed):
+def sweep(server, mp, index, cell, rates, seconds, seed):
     rows = []
     ramp = float(cell.mix["ramp_s"])
     for rate in rates:
@@ -51,7 +51,7 @@ def sweep(server, mp, cell, rates, seconds, seed):
                                  seed)
         t0 = time.perf_counter()
         w0, w1 = t0 + ramp, t0 + ramp + seconds
-        run = openloop.offer(server, plan, t0, w1)
+        run = openloop.offer(server, plan, t0, w1, index=index)
         due = t0 + plan.due
         win = (due >= w0) & (due < w1) & ~np.isnan(run.submitted)
         lat = (np.where(np.isnan(run.done), np.inf, run.done - due)[win]
@@ -87,10 +87,14 @@ def main(argv=None) -> int:
     first = _Cell(args.config, args.mix[0], args.rates[0][0])
     mp, index, server, _ = harness.setup(first, args.seed, 1.0, False,
                                          harness.index_cache.CACHE)
-    for mix, rates in zip(args.mix, args.rates):
+    cells = [_Cell(args.config, mix, rates[0]) for mix, rates in
+             zip(args.mix, args.rates)]
+    if any(traffic.path_share(c.mix) > 0 for c in cells):
+        server.warmup(paths=True)       # a later mix may ask for routes
+    for mix, rates, cell in zip(args.mix, args.rates, cells):
         print(f"== {args.config} x {mix}", flush=True)
-        cell = _Cell(args.config, mix, rates[0])
-        out[mix] = sweep(server, mp, cell, rates, args.seconds, args.seed)
+        out[mix] = sweep(server, mp, index, cell, rates, args.seconds,
+                         args.seed)
     server.stop_async()
     if args.out:
         os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)

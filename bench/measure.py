@@ -1,8 +1,9 @@
 """One run's measurements, as the metric readers (``metrics/*.py``) see them.
 
 Host-clock numbers come from ``openloop.Run``; counters are read from the
-program's ``ServeStats`` at the window's marks; device numbers come from
-the profiler trace of a ``--trace 1`` run (``tracefile.reduce``).
+program's ``ServeStats`` at the window's marks, every one it declares;
+device numbers come from the profiler trace of a ``--trace 1`` run
+(``tracefile.reduce``).
 """
 
 from __future__ import annotations
@@ -14,25 +15,30 @@ import numpy as np
 
 
 def snapshot(server) -> dict:
-    """The program's serving counters at one instant."""
+    """The program's serving counters at one instant: every counter its
+    ``ServeStats`` declares, by name, with the compile count and the
+    batches by bucket width."""
     from repro.core.packed import TRACES
 
     st = server.stats
     per_width: dict = {}
     for b in list(st.per_bucket.values()):
         per_width[b.width] = per_width.get(b.width, 0) + b.batches
-    return {"queries": st.queries, "batches": st.batches,
+    declared = getattr(type(st), "_COUNTERS", {})
+    return {**{k: getattr(st, k) for k in declared},
             "jit_traces": TRACES.count, "batches_by_width": per_width}
 
 
 def delta(a: dict, b: dict) -> dict:
+    """Each counter's change from ``a`` to ``b``; None for a counter
+    that either side lacks, as a program without it reads."""
     widths = set(a["batches_by_width"]) | set(b["batches_by_width"])
-    return {"queries": b["queries"] - a["queries"],
-            "batches": b["batches"] - a["batches"],
-            "jit_traces": b["jit_traces"] - a["jit_traces"],
-            "batches_by_width": {
-                w: b["batches_by_width"].get(w, 0)
-                - a["batches_by_width"].get(w, 0) for w in widths}}
+    out = {k: None if a.get(k) is None or b.get(k) is None else b[k] - a[k]
+           for k in (a.keys() | b.keys()) - {"batches_by_width"}}
+    out["batches_by_width"] = {
+        w: b["batches_by_width"].get(w, 0) - a["batches_by_width"].get(w, 0)
+        for w in widths}
+    return out
 
 
 class GcWatch:
@@ -83,9 +89,13 @@ class Traced:
 
 class Measurement:
     def __init__(self, seconds, setup_s, window_queries, lat_ms,
-                 traced=None):
+                 traced=None, unwind_s=(), path_requests=0):
         self.seconds = seconds            # the measured window
         self.setup_s = setup_s
         self.window_queries = window_queries  # answered inside the window
         self.lat_ms = np.asarray(lat_ms, float)   # requests due in it
         self.traced = traced
+        # path requests offered in the window, and the harness-clock
+        # seconds each answered one spent unwinding its polylines
+        self.path_requests = path_requests
+        self.unwind_s = np.asarray(unwind_s, float)

@@ -83,8 +83,15 @@ def setup(cell, seed: int, seconds: float, trace: bool, cache: str):
     from repro.launch.cache import enable_compile_cache
     from repro.serving import PathServer
 
-    enable_compile_cache(ROOT)
     cfg = cell.config
+    if cfg.get("slab_dtype") != "float32":
+        # the program's default layout is float32; a config that states
+        # another slab type would be measured on slabs it does not state
+        raise SystemExit(f"bench: config {cfg.get('name')!r} states "
+                         f"slab_dtype {cfg.get('slab_dtype')!r}; this harness "
+                         "serves float32 label slabs only")
+    enable_compile_cache(ROOT)
+    paths = traffic.path_share(cell.mix) > 0
     t = time.perf_counter()
     mp = scene.make_map(cfg)
     index, how = index_cache.build_or_load(cfg, mp, SRC, log=log,
@@ -96,7 +103,7 @@ def setup(cell, seed: int, seconds: float, trace: bool, cache: str):
         if trace else None
     server = PathServer(index, telemetry=tel)
     t_pack = time.perf_counter()
-    server.warmup()
+    server.warmup(paths=paths)
     t_warm = time.perf_counter()
     plan = traffic.make_plan(mp, cell.mix, cfg, cell.rate, seconds, seed)
     server.start_async()
@@ -108,7 +115,8 @@ def setup(cell, seed: int, seconds: float, trace: bool, cache: str):
         f"{t_pack - t_index:.2f}s ({eng.device_bytes() / 1e6:.1f} MB on "
         f"device, widths {widths}), warm {t_warm - t_pack:.2f}s, traffic "
         f"{t_plan - t_warm:.2f}s ({len(plan.due)} requests x "
-        f"{plan.per_request}, pool {len(plan.s)} queries)")
+        f"{plan.per_request}, {int(plan.path.sum())} of them path requests, "
+        f"pool {len(plan.s)} queries)")
     return mp, index, server, plan
 
 
@@ -152,7 +160,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devices,
 
     with measure.GcWatch() as gcw:
         run = openloop.offer(server, plan, t0, w1, marks=marks,
-                             annotate=annotate)
+                             annotate=annotate, index=index)
     due = t0 + plan.due
     in_window = (due >= w0) & (due < w1)
     offered = in_window & ~np.isnan(run.submitted)
@@ -177,6 +185,12 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devices,
         f"{len(run.errors)} submit errors")
     q = plan.per_request
     done_in = (run.done >= w0) & (run.done <= w1)
+    routed = offered & plan.path
+    unwind_s = run.unwind_s[routed & ~np.isnan(run.done)]
+    if routed.any():
+        log(f"path requests: {int(routed.sum())} offered in the window, "
+            f"unwinding {1e6 * unwind_s.mean() / q if unwind_s.size else 0:.1f}"
+            f" us a query on the waiter thread")
     lat_ms = np.where(np.isnan(run.done), np.inf, run.done - due)[
         in_window & ~np.isnan(run.submitted)] * 1e3
     if lat_ms.size:
@@ -208,19 +222,22 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devices,
             spans, red, bs, edges, work.peaks(devices[0].device_kind),
             gcw.between(i0, i1))
     m = measure.Measurement(seconds, setup_s, int(done_in.sum()) * q,
-                            lat_ms, traced)
+                            lat_ms, traced, unwind_s=unwind_s,
+                            path_requests=int(routed.sum()))
     metrics = {}
     for entry in (cell.per_layer if trace else cell.end_to_end):
         v = spec.reader(entry["name"])(m)
         if v is not None:
             metrics[entry["name"]] = {"value": v, "unit": entry["unit"]}
-    s, t, answers = check.sample(plan, run, offered, seed)
+    s, t, answers, routes = check.sample(plan, run, offered, seed)
     server.stop_async()
     del server, index, run
     gc.collect()
     t_ref = time.perf_counter()
     compared = check.compare(reference.Reference(mp), s, t, answers,
-                             unanswered, cell.limit)
+                             unanswered, cell.limit,
+                             routes if traffic.path_share(cell.mix) > 0
+                             else None)
     log(f"reference: {len(s)} queries in "
         f"{time.perf_counter() - t_ref:.2f}s")
     d0 = devices[0]

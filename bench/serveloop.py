@@ -7,8 +7,9 @@ per retired batch, and the time and routing part of each ``submit()``
 call.  While ``telemetry.timeline`` is on, it also writes each stage of the
 loop as a host event on the JAX profiler's clock (DESIGN.md §12).
 
-* ``snapshot(server)`` / ``delta(a, b)``: those counters at one instant and
-  their change over an interval.  A program without them reads None.
+* ``measure.snapshot`` / ``measure.delta`` carry those counters, with
+  every other one ``ServeStats`` declares; a program without one reads
+  None.
 * ``split_idle(pd, window_ns)``: the device's idle time inside
   ``[0, window_ns]``, split by the ``serve.*`` span that the loop's thread
   was in, by exact interval overlap; None when no host line holds the
@@ -17,33 +18,20 @@ loop as a host event on the JAX profiler's clock (DESIGN.md §12).
 * ``per`` and ``idle_share``: the arithmetic of the metric readers
   ``launch_lag_ms.tail``, ``retire_us.tail``, ``admit_us.tail``,
   ``route_us.tail``, ``idle_wait_share.tail`` and
-  ``idle_host_share.tail``.  They read ``Traced.counters`` with this
-  module's ``delta`` merged in, and ``Traced.red["serve_idle"]``; where
-  the run did not record them, they read None.
+  ``idle_host_share.tail``.  They read ``Traced.counters`` and
+  ``Traced.red["serve_idle"]``; where the run did not record them, they
+  read None.
 """
 
 from __future__ import annotations
 
 import tracefile
 
-COUNTERS = ("launch_lag_seconds", "retire_seconds", "retired_batches",
-            "submit_calls", "admit_seconds", "route_seconds")
-
 WAIT = ("serve.wait",)
 HOST = ("serve.stage", "serve.dispatch", "serve.fetch", "serve.scatter",
         "serve.observe")
 JOIN = ("serve.join",)
 PARTS = (("wait", WAIT), ("host", HOST), ("join", JOIN))
-
-
-def snapshot(server) -> dict:
-    st = server.stats
-    return {k: getattr(st, k, None) for k in COUNTERS}
-
-
-def delta(a: dict, b: dict) -> dict:
-    return {k: None if a.get(k) is None or b.get(k) is None
-            else b[k] - a[k] for k in COUNTERS}
 
 
 def _length(ivs) -> float:

@@ -1,5 +1,6 @@
 """The serve loop's records: device idle time split by the loop's
-``serve.*`` spans, the counter snapshot, and the six readers built on them.
+``serve.*`` spans, the counter snapshot (``measure.snapshot``), and the six
+readers built on them.
 
 Hand-built planes as in ``test_tracefile.py``; the recorded chip trace
 (``data/chip_trace.xplane.pb.gz``) predates the spans.
@@ -125,32 +126,59 @@ def _measurement(counters=None, red=None):
     return measure.Measurement(1.0, 1.0, 0, [1.0], traced)
 
 
+COUNTERS = ("launch_lag_seconds", "retire_seconds", "retired_batches",
+            "submit_calls", "admit_seconds", "route_seconds")
+
+
+def _server(**counters):
+    """A server whose ``ServeStats`` declares just ``counters``."""
+    stats = type("Stats", (), {"_COUNTERS": dict.fromkeys(counters),
+                               "per_bucket": {}, **counters})
+    return NS(stats=stats())
+
+
 def test_snapshot_of_a_program_without_the_counters_reads_none():
-    old = NS(stats=NS(queries=5, batches=1))
-    snap = serveloop.snapshot(old)
-    assert snap == {k: None for k in serveloop.COUNTERS}
-    assert serveloop.delta(snap, snap) == snap
+    old = _server(queries=5, batches=1)
+    snap = measure.snapshot(old)
+    assert {k: snap.get(k) for k in COUNTERS} == {k: None for k in COUNTERS}
+    d = measure.delta(snap, snap)
+    assert (d["queries"], d["batches"], d["jit_traces"]) == (0, 0, 0)
     for name in READERS:
-        assert spec.reader(name)(_measurement({"queries": 5, "batches": 1,
-                                               **snap})) is None
+        assert spec.reader(name)(_measurement(d)) is None
+
+
+def test_snapshot_carries_every_counter_the_program_declares():
+    from repro.serving.engine import ServeStats
+
+    snap = measure.snapshot(NS(stats=ServeStats()))
+    assert set(ServeStats._COUNTERS) <= set(snap)
+    assert set(COUNTERS) <= set(snap)
+    # a counter added to the program later is carried by name, no edit
+    d = measure.delta(measure.snapshot(_server(queries=1, frobs=2)),
+                      measure.snapshot(_server(queries=4, frobs=7)))
+    assert (d["queries"], d["frobs"]) == (3, 5)
+    # one the other side lacks reads None
+    d = measure.delta(measure.snapshot(_server(queries=1)),
+                      measure.snapshot(_server(queries=4, frobs=7)))
+    assert d["frobs"] is None
 
 
 def test_readers_read_none_when_their_counters_did_not_move():
-    zero = {k: 0 for k in serveloop.COUNTERS}
+    zero = {k: 0 for k in COUNTERS}
     m = _measurement({"queries": 0, "batches": 0, **zero})
     for name in READERS:
         assert spec.reader(name)(m) is None
 
 
 def test_readers_divide_the_interval_deltas():
-    st0 = NS(stats=NS(launch_lag_seconds=1.0, retire_seconds=2.0,
-                      retired_batches=10, submit_calls=100,
-                      admit_seconds=0.5, route_seconds=0.1))
-    st1 = NS(stats=NS(launch_lag_seconds=1.8, retire_seconds=2.05,
-                      retired_batches=110, submit_calls=2100,
-                      admit_seconds=0.9, route_seconds=0.3))
-    d = serveloop.delta(serveloop.snapshot(st0), serveloop.snapshot(st1))
-    m = _measurement({"queries": 400, "batches": 100, **d})
+    st0 = _server(queries=0, batches=0, launch_lag_seconds=1.0,
+                  retire_seconds=2.0, retired_batches=10, submit_calls=100,
+                  admit_seconds=0.5, route_seconds=0.1)
+    st1 = _server(queries=400, batches=100, launch_lag_seconds=1.8,
+                  retire_seconds=2.05, retired_batches=110,
+                  submit_calls=2100, admit_seconds=0.9, route_seconds=0.3)
+    d = measure.delta(measure.snapshot(st0), measure.snapshot(st1))
+    m = _measurement(d)
     got = {n: spec.reader(n)(m) for n in READERS[:4]}
     assert got == {
         "launch_lag_ms.tail": pytest.approx(1e3 * 0.8 / 400),

@@ -111,3 +111,53 @@ def test_poisson_arrivals_keep_the_rate(room64):
     span = _mix("single-uniform")["ramp_s"] + 10.0
     assert len(plan.due) == pytest.approx(2000.0 * span, rel=0.03)
     assert plan.due[-1] < span
+
+
+# sha256 of due, s and t (first 24 hex digits) as the generator drew them
+# before mixes could ask for routes: 3200 requests/s, 2 s, two seeds
+DRAWN = {("room64-b20", "single-uniform", 5): "a489926cbf6d7060e749937f",
+         ("room64-b20", "single-uniform", 2**31 + 977):
+             "c1c971e17ea38c08462bedcf",
+         ("room64-wa10", "single-cluster4", 5): "ecec3b9ef0fd0ee6edccd845",
+         ("room64-wa10", "single-cluster4", 2**31 + 977):
+             "f308e8752fd5b90a87e9d01a"}
+
+
+def _digest(plan):
+    import hashlib
+    h = hashlib.sha256()
+    for a in (plan.due, plan.s, plan.t):
+        h.update(np.ascontiguousarray(a).tobytes())
+    return h.hexdigest()[:24]
+
+
+@pytest.mark.parametrize("key", sorted(DRAWN, key=str))
+def test_existing_mixes_draw_the_same_plan_bit_for_bit(key):
+    """A mix without ``path_share`` draws exactly the plan it drew before
+    path requests existed, and asks for no route; the same mix with the
+    key draws the same due times and points."""
+    name, mix, seed = key
+    cfg = _config(name)
+    mp = scene.make_map(cfg)
+    plan = traffic.make_plan(mp, _mix(mix), cfg, 3200.0, 2.0, seed)
+    assert _digest(plan) == DRAWN[key]
+    assert plan.path.dtype == bool and not plan.path.any()
+    routed = traffic.make_plan(mp, dict(_mix(mix), path_share=0.3), cfg,
+                               3200.0, 2.0, seed)
+    assert _digest(routed) == DRAWN[key]
+
+
+def test_path_share_draws_its_share_from_its_own_stream(room64):
+    cfg, mp = room64
+    mix = dict(_mix("single-uniform"), path_share=0.3)
+    a = traffic.make_plan(mp, mix, cfg, 2000.0, 10.0, seed=2**33 + 1)
+    b = traffic.make_plan(mp, mix, cfg, 2000.0, 10.0, seed=2**33 + 1)
+    c = traffic.make_plan(mp, mix, cfg, 2000.0, 10.0, seed=2**33 + 2)
+    assert a.path.shape == a.due.shape
+    np.testing.assert_array_equal(a.path, b.path)
+    assert not np.array_equal(a.path, c.path)
+    assert a.path.mean() == pytest.approx(0.3, abs=0.01)
+    for share in (-0.1, 1.5):
+        with pytest.raises(ValueError):
+            traffic.make_plan(mp, dict(mix, path_share=share), cfg, 10.0,
+                              1.0, 1)

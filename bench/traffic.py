@@ -14,6 +14,10 @@ Mix keys:
 ``clusters``             k, for ``cluster`` endpoints; the config's
                          ``clusters`` entry must place k rectangles.
 ``ramp_s``               seconds of load offered before the window opens.
+``path_share``           share of requests that ask for routes (absent: 0):
+                         each request is a path request with this
+                         probability, drawn from a stream of its own, so a
+                         mix without the key draws the same plan as before.
 
 The request rate is the cell's (``cells/<cell>.json``).  Copies of the
 program's Unknown and Cluster-k workloads (``repro.core.workload``),
@@ -28,6 +32,7 @@ import numpy as np
 
 
 POOL = 1 << 17        # distinct queries drawn per run; requests cycle them
+PATH_STREAM = 0x9A7   # the path draw's own stream beside the seed
 
 
 @dataclasses.dataclass
@@ -36,6 +41,11 @@ class Plan:
     s: np.ndarray            # [P, 2] float32 query sources (the pool)
     t: np.ndarray            # [P, 2] float32 query targets
     per_request: int         # q
+    path: np.ndarray | None = None   # [R] bool: request asks for routes
+
+    def __post_init__(self):
+        if self.path is None:
+            self.path = np.zeros(len(self.due), bool)
 
     def rows(self, i: int) -> slice:
         """Pool rows of request ``i`` (requests cycle through the pool)."""
@@ -111,6 +121,14 @@ def endpoints(mp, mix: dict, cfg: dict, n: int, rng) -> tuple:
     raise ValueError(f"unknown endpoints {kind!r}")
 
 
+def path_share(mix: dict) -> float:
+    """The mix's share of path requests (0 where it names none)."""
+    share = float(mix.get("path_share", 0.0))
+    if not 0.0 <= share <= 1.0:
+        raise ValueError(f"path_share {share} is not in [0, 1]")
+    return share
+
+
 def make_plan(mp, mix: dict, cfg: dict, rate: float, seconds: float,
               seed: int) -> Plan:
     """Every request offered in a run: ramp plus window at ``rate``
@@ -132,5 +150,10 @@ def make_plan(mp, mix: dict, cfg: dict, rate: float, seconds: float,
     q = int(mix["queries_per_request"])
     n = min(len(due), max(1, POOL // q)) * q
     s, t = endpoints(mp, mix, cfg, n, rng)
+    share = path_share(mix)
+    path = np.zeros(len(due), bool)
+    if share > 0:
+        path = np.random.default_rng([seed, PATH_STREAM]).random(
+            len(due)) < share
     return Plan(due=due, s=s.astype(np.float32), t=t.astype(np.float32),
-                per_request=q)
+                per_request=q, path=path)
