@@ -1152,16 +1152,33 @@ def _join_endpoints(idx, masked_s, masked_t, pts: jnp.ndarray,
                     use_kernels: bool = False, want_argmin: bool = False):
     """Co-visibility + Eq. 1-3 join over folded endpoint sides (jit entry).
 
-    ``pts`` is the same [2, B, 2] stack the fold read.  Quantized argmin
-    joins also flag ambiguous rows against the summed per-side error
-    bound (:func:`_join_masked`)."""
+    ``pts`` is the same [2, B, 2] stack the fold read.  An f32 argmin join
+    returns its five answers as one packed [5, B] int32 array
+    (:func:`unpack_argmin`), so a batch crosses to the host in one copy.
+    Quantized argmin joins return six arrays: the five answers and the
+    rows flagged ambiguous against the summed per-side error bound
+    (:func:`_join_masked`)."""
     TRACES.bump("join_endpoints")
     s, t = pts[0], pts[1]
     covis = _segvis(s, t, _edges_of(idx), use_kernels)
     qerr2 = (idx.qerr + idx.qerr
              if want_argmin and idx.layout.quantized else None)
-    return _join_masked(masked_s, masked_t, s, t, covis, use_kernels,
-                        want_argmin, qerr2=qerr2)
+    res = _join_masked(masked_s, masked_t, s, t, covis, use_kernels,
+                       want_argmin, qerr2=qerr2)
+    if not want_argmin or idx.layout.quantized:
+        return res
+    d, cv, via_s, hub, via_t = res
+    return jnp.stack([jax.lax.bitcast_convert_type(d, jnp.int32),
+                      cv.astype(jnp.int32), via_s, hub, via_t])
+
+
+def unpack_argmin(packed, n: int | None = None) -> list:
+    """Host (d, covis, via_s, hub, via_t) columns, rows ``[:n]``, of a
+    packed [5, B] argmin join (:func:`_join_endpoints`): one device->host
+    copy, then the :func:`empty_results` dtypes.  ``d`` is the float32
+    view of its row, so every bit, inf included, is the join's own."""
+    a = np.asarray(packed)[:, :n]
+    return [a[0].view(np.float32), a[1].astype(bool), a[2], a[3], a[4]]
 
 
 def query_stacked(idx, pts: jnp.ndarray, bucket: int | None = None,
@@ -1173,8 +1190,10 @@ def query_stacked(idx, pts: jnp.ndarray, bucket: int | None = None,
     comes from :func:`stack_endpoints`.  ``bucket``: None for the single
     PackedIndex slab, else the bucketed layout's dispatch bucket.  With
     ``want_argmin`` the result carries the winning (covis, via_s, hub,
-    via_t) ids; quantized layouts add a sixth ``amb`` array — rows the
-    caller must rescue against the residual (:func:`rescue_exact`).
+    via_t) ids: for f32 layouts packed with ``d`` into one [5, B] int32
+    array (:func:`unpack_argmin`); quantized layouts return the five
+    arrays and a sixth ``amb`` — rows the caller must rescue against the
+    residual (:func:`rescue_exact`).
     """
     ms, mt = _fold_endpoint(idx, pts, bucket=bucket, use_kernels=use_kernels)
     return _join_endpoints(idx, ms, mt, pts, use_kernels=use_kernels,
@@ -1196,12 +1215,21 @@ def query_batch(idx: PackedIndex, s, t,
 def query_batch_argmin(idx: PackedIndex, s, t, use_kernels: bool = False):
     """Distances + winning (via_s, hub, via_t) label ids (path unwinding).
 
-    Same single transfer and two dispatches as :func:`query_batch`.
-    Quantized layouts return a sixth ``amb`` array — rows the caller must
-    rescue against the residual (:func:`rescue_exact`) for exact argmin.
+    Same single transfer and two dispatches as :func:`query_batch`; f32
+    layouts return the five host columns (:func:`unpack_argmin`).
+    Quantized layouts return the six device arrays — ``amb`` flags rows
+    the caller must rescue against the residual (:func:`rescue_exact`)
+    for exact argmin.
     """
-    return query_stacked(idx, stack_endpoints(s, t), use_kernels=use_kernels,
-                         want_argmin=True)
+    return _argmin_columns(idx, query_stacked(
+        idx, stack_endpoints(s, t), use_kernels=use_kernels,
+        want_argmin=True))
+
+
+def _argmin_columns(idx, res) -> tuple:
+    """An argmin join's answers as a tuple: f32 layouts' packed array
+    unpacked on the host, quantized layouts' six arrays as they are."""
+    return tuple(res if idx.layout.quantized else unpack_argmin(res))
 
 
 # ---------------------------------------------------------------------------
@@ -1281,10 +1309,12 @@ def query_batch_at_bucket(bx: BucketedIndex, s, t, bucket: int,
     then bitwise-identical to the full-width ``query_batch`` because the
     extra slots it would have carried are all inf/HUB_PAD padding.  One
     transfer of both endpoint sides, one fold and one join dispatch
-    (:func:`query_stacked`).
+    (:func:`query_stacked`); argmin answers come back as
+    :func:`query_batch_argmin` returns them.
     """
-    return query_stacked(bx, stack_endpoints(s, t), bucket=bucket,
-                         use_kernels=use_kernels, want_argmin=want_argmin)
+    res = query_stacked(bx, stack_endpoints(s, t), bucket=bucket,
+                        use_kernels=use_kernels, want_argmin=want_argmin)
+    return _argmin_columns(bx, res) if want_argmin else res
 
 
 # ---------------------------------------------------------------------------

@@ -516,7 +516,7 @@ class CoalescingBatcher:
             dt = t_joined - f.t_launch
             n = len(f.entries)
             with tel.span("serve.fetch"):
-                outs = [np.asarray(r)[:n] for r in f.res]
+                outs = f.eng.fetch(f.res, n)
             with tel.span("serve.scatter"):
                 # counted before any ticket completes: a caller that reads
                 # the stats after result() sees its own queries
@@ -552,6 +552,8 @@ class CoalescingBatcher:
                     t = np.stack([e.t for e in f.entries])
                     srv._recorder.record(s, t)
                 stats.inc("retired_batches")
+                stats.inc("fetched_arrays", sum(isinstance(r, jax.Array)
+                                                for r in f.res))
                 stats.inc("retire_seconds", time.perf_counter() - t_joined)
         finally:
             f.pin_cm.__exit__(None, None, None)

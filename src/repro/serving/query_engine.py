@@ -33,7 +33,8 @@ from repro.core.grid import EHLIndex
 from repro.core.packed import (BucketedIndex, LAYOUT_F32, PackedIndex,
                                gather_masked_exact, join_masked,
                                pack_bucketed, query_stacked,
-                               rescue_exact, splice_rescue, stack_endpoints)
+                               rescue_exact, splice_rescue, stack_endpoints,
+                               unpack_argmin)
 from repro.core.query import query as host_query
 
 
@@ -89,14 +90,22 @@ class QueryEngine(abc.ABC):
                         want_argmin: bool = False) -> tuple:
         """Dispatch a staged batch WITHOUT synchronizing.
 
-        Returns a tuple of result arrays (1 without argmin, 5 with) that
-        may still be computing on device — the caller owns
-        ``block_until_ready``, which is what lets the batcher overlap the
-        next group's staging with this group's compute."""
+        Returns a tuple of result arrays (1 without argmin, 5 with, or
+        the engine's own packed form) that may still be computing on
+        device — the caller owns ``block_until_ready`` and reads them with
+        :meth:`fetch`, which is what lets the batcher overlap the next
+        group's staging with this group's compute."""
         s, t = staged
         if want_argmin:
             return tuple(self.batch_argmin(s, t, bucket=bucket))
         return (self.batch(s, t, bucket=bucket),)
+
+    def fetch(self, res, n: int) -> list:
+        """Host columns, rows ``[:n]``, of a :meth:`dispatch_staged`
+        result, in the :func:`~repro.core.packed.empty_results` dtypes:
+        one copy per array."""
+        # repolint: disable=hot-path-sync -- the retire's copy after the batcher's join, and batch_argmin's synchronous return
+        return [np.asarray(r)[:n] for r in res]
 
     def warmup(self, batch_size: int, want_argmin: bool = False) -> None:
         pass
@@ -174,10 +183,13 @@ class DeviceEngine(QueryEngine):
                              use_kernels=self.use_kernels,
                              want_argmin=want_argmin)
 
-    def _argmin(self, pts, bucket: int):
+    def _argmin(self, pts, bucket: int) -> tuple:
+        """Launch an argmin batch: the packed [5, B] join of f32 layouts,
+        unsynchronized, or the quantized layouts' five host columns after
+        their rescue."""
         res = self._run(pts, bucket, want_argmin=True)
         if not self.quantized:
-            return res
+            return (res,)
         # quantized: 6-tuple — rescue ambiguous-margin rows against the
         # exact residual so argmin winners match the f32 engine bitwise
         # repolint: disable=hot-path-sync -- documented rescue trigger: one flag word, the exactness contract pays this sync
@@ -197,14 +209,15 @@ class DeviceEngine(QueryEngine):
             obs.REGISTRY.histogram("rescue_ms", engine=self.name).record(
                 sw.seconds * 1e3)
             return out
-        # repolint: disable=hot-path-sync -- batch_argmin is the synchronous API; host results are its contract
+        # repolint: disable=hot-path-sync -- the amb check above synced the batch; quantized argmin results are host columns
         return tuple(np.asarray(r) for r in res[:5])
 
     def batch(self, s, t, bucket: int = 0) -> np.ndarray:
         return self._run(self.stage(s, t), bucket, want_argmin=False)
 
     def batch_argmin(self, s, t, bucket: int = 0):
-        return self._argmin(self.stage(s, t), bucket)
+        return tuple(self.fetch(self._argmin(self.stage(s, t), bucket),
+                                len(s)))
 
     def stage(self, s, t, bucket: int = 0):
         """Stack both endpoint sides into one [2, B, 2] float32 array and
@@ -215,10 +228,18 @@ class DeviceEngine(QueryEngine):
     def dispatch_staged(self, staged, bucket: int = 0,
                         want_argmin: bool = False) -> tuple:
         """Fold and join dispatches over the staged array, unsynchronized
-        (quantized argmin batches sync for their rescue)."""
+        (quantized argmin batches sync for their rescue).  An f32 argmin
+        batch is one packed array (:meth:`fetch` unpacks it)."""
         if want_argmin:
-            return tuple(self._argmin(staged, bucket))
+            return self._argmin(staged, bucket)
         return (self._run(staged, bucket, want_argmin=False),)
+
+    def fetch(self, res, n: int) -> list:
+        """As :meth:`QueryEngine.fetch`; a packed [5, B] argmin join (the
+        one array of rank 2) is copied once and unpacked."""
+        if len(res) == 1 and res[0].ndim == 2:
+            return unpack_argmin(res[0], n)
+        return super().fetch(res, n)
 
     def warmup(self, batch_size: int, want_argmin: bool = False) -> None:
         """Trace every per-bucket jit entry once with the serving shape.

@@ -350,3 +350,51 @@ def test_launch_is_one_transfer_and_two_programs(real_server, queries_s,
     for r, g in zip(ref, got if want_argmin else (got,)):
         np.testing.assert_array_equal(np.asarray(r), np.asarray(g))
     b.close(drain=False)
+
+
+@pytest.mark.parametrize("want_argmin", [False, True],
+                         ids=["dist", "argmin"])
+@pytest.mark.parametrize("bucket", (0, 1))   # the lattice's bucket ladder
+def test_retire_copies_one_array_to_the_host(real_server, queries_s,
+                                             monkeypatch, bucket,
+                                             want_argmin):
+    """Retiring one batch copies one device array to the host, argmin or
+    not (``fetched_arrays`` and the ``np.asarray`` calls on device arrays
+    inside ``_retire`` agree), and its tickets read bitwise what the
+    synchronous ``batch`` / ``batch_argmin`` answer."""
+    import jax
+
+    srv = real_server
+    s = queries_s.s.astype(np.float32)
+    t = queries_s.t.astype(np.float32)
+    m = srv.engine.buckets_of(s, t) == bucket
+    s, t = s[m][:srv.batch_size], t[m][:srv.batch_size]
+    assert len(s)
+    b = CoalescingBatcher(srv, autostart=False)
+    tk = b.submit(s, t, want_argmin=want_argmin)
+    b.flush()
+    flight = b._launch(*b._pop_ready(block=False))
+    assert flight.key == bucket
+
+    copies = []
+    asarray = np.asarray
+
+    def counted(a, *args, **kw):
+        if isinstance(a, jax.Array):
+            copies.append(a.shape)
+        return asarray(a, *args, **kw)
+
+    before = srv.stats.fetched_arrays
+    monkeypatch.setattr(np, "asarray", counted)
+    b._retire(flight)
+    monkeypatch.undo()
+    assert len(copies) == srv.stats.fetched_arrays - before == 1
+    got = tk.result(timeout=1)
+    with srv.engine.pin() as eng:
+        ref = (eng.batch_argmin(s, t, bucket=bucket) if want_argmin
+               else (eng.batch(s, t, bucket=bucket),))
+    for r, g in zip(ref, got if want_argmin else (got,)):
+        r = np.asarray(r)
+        assert g.dtype == r.dtype
+        np.testing.assert_array_equal(g.view(np.uint8), r.view(np.uint8))
+    b.close(drain=False)

@@ -163,8 +163,8 @@ def test_stacked_fold_is_row_independent(conformance, bucket, layout,
     m = eng.buckets_of(s, t) <= bucket
     assert m.any()
     sb, tb = s[m], t[m]
-    staged = eng.dispatch_staged(eng.stage(sb, tb), bucket=bucket,
-                                 want_argmin=want_argmin)
+    staged = eng.fetch(eng.dispatch_staged(eng.stage(sb, tb), bucket=bucket,
+                                           want_argmin=want_argmin), len(sb))
     sync = (eng.batch_argmin(sb, tb, bucket=bucket) if want_argmin
             else (eng.batch(sb, tb, bucket=bucket),))
     assert len(staged) == len(sync) == (5 if want_argmin else 1)
@@ -177,3 +177,63 @@ def test_stacked_fold_is_row_independent(conformance, bucket, layout,
     assert np.array_equal(fin, np.isfinite(d))
     np.testing.assert_allclose(d[fin], truth[fin], rtol=HOST_TOL,
                                atol=HOST_TOL + 2.0 * conformance.qerr(layout))
+
+
+
+ARGMIN_DTYPES = (np.float32, np.bool_, np.int32, np.int32, np.int32)
+
+
+@pytest.mark.parametrize("bucket", BUCKETS)
+def test_packed_argmin_join_unpacks_bitwise(conformance, bucket):
+    """An f32 argmin join is one packed [5, B] int32 array that unpacks,
+    bit for bit (``d``'s inf included), into the five outputs the plain
+    join computes from the same folded inputs; the library callers return
+    those five columns, in the engine dtypes."""
+    from repro.core.packed import (_edges_of, _fold_endpoint,
+                                   _join_endpoints, _segvis, join_masked,
+                                   query_batch_argmin, stack_endpoints,
+                                   unpack_argmin)
+    from repro.serving.query_engine import JnpEngine
+
+    def plain(idx, s, t, bucket, hide=None):
+        """Fold, then the unpacked join's five outputs and the packed one;
+        ``hide``: a row whose s side reaches no label."""
+        pts = stack_endpoints(s, t)
+        ms, mt = _fold_endpoint(idx, pts, bucket=bucket)
+        if hide is not None:
+            ms = (ms[0], ms[1].at[hide].set(jnp.inf), ms[2])
+        covis = _segvis(pts[0], pts[1], _edges_of(idx), False)
+        ref = join_masked(ms, mt, pts[0], pts[1], covis, want_argmin=True)
+        return ([np.asarray(r) for r in ref],
+                _join_endpoints(idx, ms, mt, pts, want_argmin=True))
+
+    bx = conformance.bucketed("f32")
+    s, t = conformance.s, conformance.t
+    # every query at this bucket (rows of a wider bucket read inf), one of
+    # them not co-visible and cut off from its labels
+    ref, _ = plain(bx, s, t, bucket)
+    ref, packed = plain(bx, s, t, bucket, hide=int(np.argmin(ref[1])))
+    assert packed.shape == (5, len(s)) and packed.dtype == jnp.int32
+    assert np.isinf(ref[0]).any() and np.isfinite(ref[0]).any()
+    got = unpack_argmin(packed)
+    np.testing.assert_array_equal(got[0].view(np.int32),
+                                  ref[0].view(np.int32))
+    for g, r, dt in zip(got, ref, ARGMIN_DTYPES):
+        assert g.dtype == dt
+        np.testing.assert_array_equal(g, r)
+
+    # the library callers: the same five host columns, same dtypes
+    eng = JnpEngine(bx)
+    m = eng.buckets_of(s, t) <= bucket
+    pk = pack_index(conformance.idx)
+    cases = ((query_batch_at_bucket(bx, s[m], t[m], bucket,
+                                    want_argmin=True),
+              plain(bx, s[m], t[m], bucket)[0]),
+             (eng.batch_argmin(s[m], t[m], bucket=bucket),
+              plain(bx, s[m], t[m], bucket)[0]),
+             (query_batch_argmin(pk, s, t), plain(pk, s, t, None)[0]))
+    for res, want in cases:
+        assert len(res) == 5
+        for r, w, dt in zip(res, want, ARGMIN_DTYPES):
+            assert isinstance(r, np.ndarray) and r.dtype == dt
+            np.testing.assert_array_equal(r.view(np.uint8), w.view(np.uint8))
